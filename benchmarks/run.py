@@ -98,6 +98,8 @@ def main(argv=None) -> int:
                     help="also write the verdict rows as JSON (CI "
                          "artifact)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     only = [s.strip() for s in args.only.split(",") if s.strip()] or SUITES
     gating = args.compare or args.update_baselines
     reruns = max(1, args.reruns)

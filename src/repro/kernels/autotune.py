@@ -1,13 +1,14 @@
 """Persistent per-cell autotuner for the Pallas dispatch layer.
 
 The kernels' tile shapes (``batch_tile``, the level ``split`` of the cone
-kernel, the Gram block shapes) are workload-dependent: interpret mode pays
-~4x wasted compute when B=32 is padded to the default ``batch_tile=128``,
-while a compiled TPU run wants the full 128-lane tile.  This module keeps a
+kernel, the Gram block shapes) are workload-dependent.  This module keeps a
 small JSON cache of measured winners keyed by dispatch *cell* — (kind, d,
-depth, pow2-bucketed M and B, engine, precision) — which
-:mod:`repro.kernels.ops` consults whenever the caller does not pass an
-explicit tile.
+depth, pow2-bucketed M and B, engine, precision) and by the device the
+winner was timed on (``platform``, ``device_kind``), so a record swept on
+one device is never applied on another — which :mod:`repro.kernels.ops`
+consults whenever the caller does not pass an explicit tile.  A sweep times
+the engine the cell names: ``pallas`` compiled on the TPU,
+``pallas_interpret`` in interpret mode.
 
 Environment control (read per call, so tests can monkeypatch):
 
@@ -36,6 +37,7 @@ set of cells and writes the cache (used by the CI benchmark job).
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import time
@@ -90,10 +92,19 @@ def _bucket(n: int) -> int:
 _BUCKETED = ("M", "B", "Bx", "By", "D")
 
 
+def _device() -> dict:
+    """The device a record is timed on and looked up for."""
+    import jax
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind}
+
+
 def cell_key(kind: str, **cell) -> str:
     """Canonical cache key.  Size-like axes (M, B, Bx, By, D) are bucketed
     to the next power of two; structural axes (d, depth, engine, precision)
-    are exact."""
+    and the device (``platform`` and ``device_kind``, the current device's
+    unless given) are exact."""
+    cell = {**_device(), **cell}
     parts = [kind]
     for k in sorted(cell):
         v = cell[k]
@@ -198,19 +209,16 @@ def lookup(kind: str, **cell) -> dict:
 # sweeps
 # ---------------------------------------------------------------------------
 
-def _median_time(fn, repeats: int = 3) -> float:
-    fn()  # compile + warm caches
-    ts = []
+def _median_times(fns: list, repeats: int = 3) -> list[float]:
+    """Median seconds of each already-warm ``fn``, timed round-robin so
+    that no candidate alone pays for the process warming up."""
+    ts: list[list[float]] = [[] for _ in fns]
     for _ in range(repeats):
-        t0 = time.perf_counter()
-        fn()
-        ts.append(time.perf_counter() - t0)
-    ts.sort()
-    return ts[len(ts) // 2]
-
-
-def _backend_name(engine: str) -> str:
-    return "pallas_interpret" if engine == "pallas" else engine
+        for t, fn in zip(ts, fns):
+            t0 = time.perf_counter()
+            fn()
+            t.append(time.perf_counter() - t0)
+    return [sorted(t)[len(t) // 2] for t in ts]
 
 
 def _pick(timed: list[tuple[float, dict]], default: dict,
@@ -224,9 +232,15 @@ def _pick(timed: list[tuple[float, dict]], default: dict,
     return default
 
 
+def _lane_tiles(n: int) -> list[int]:
+    """Lane-tile candidates for an axis of ``n`` rows: multiples of 128
+    (the kernels shrink a tile wider than the axis to the whole axis, see
+    :func:`repro.kernels.sig_trunc.lane_tile`)."""
+    return [128, 256] if n > 128 else [128]
+
+
 def _sig_candidates(depth: int, B: int) -> list[dict]:
-    tiles = sorted({bt for bt in (8, 32, 128) if bt >= 8} |
-                   {min(128, max(8, _bucket(B)))})
+    tiles = _lane_tiles(B)
     splits = sorted({s for s in (None, 2, depth - 1) if s is None
                      or 1 <= s < depth}, key=lambda s: -1 if s is None else s)
     return [{"batch_tile": bt, "split": sp} for bt in tiles for sp in splits]
@@ -242,53 +256,48 @@ def sweep_cell(kind: str, cell: dict, repeats: int = 3) -> dict:
     import numpy as np
     from repro.kernels import ops
 
-    engine = cell.get("engine", "pallas")
-    if engine == "jax":
+    backend = cell.get("engine", "pallas")
+    if backend == "jax":
         return {}
-    backend = _backend_name(engine)
     precision = cell.get("precision", "fp32")
     rng = np.random.default_rng(0)
     _sweeping = True
     try:
-        timed: list[tuple[float, dict]] = []
         if kind in ("sig_trunc", "sig_words"):
             B, M, d, depth = (cell["B"], cell["M"], cell["d"], cell["depth"])
-            x = jax.numpy.asarray(
-                rng.standard_normal((B, M, d), np.float32) * 0.1)
+            args = (jax.numpy.asarray(
+                rng.standard_normal((B, M, d), np.float32) * 0.1),)
             if kind == "sig_trunc":
                 cands = _sig_candidates(depth, B)
                 default = {"batch_tile": 128, "split": None}
 
-                def run(rec):
+                def run(rec, x):
                     return ops.signature(
                         x, depth, backend=backend, precision=precision,
                         batch_tile=rec["batch_tile"], split=rec["split"])
             else:
                 from repro.core.words import all_words
                 words = tuple(all_words(d, depth))
-                cands = [{"batch_tile": bt}
-                         for bt in sorted({8, 32, 128} |
-                                          {min(128, max(8, _bucket(B)))})]
+                cands = [{"batch_tile": bt} for bt in _lane_tiles(B)]
                 default = {"batch_tile": 128}
 
-                def run(rec):
+                def run(rec, x):
                     return ops.projected(
                         x, words, backend=backend, precision=precision,
                         batch_tile=rec["batch_tile"])
         elif kind == "gram":
             D, Bx, By = cell["D"], cell["Bx"], cell["By"]
-            Sx = jax.numpy.asarray(
-                rng.standard_normal((Bx, D), np.float32) * 0.1)
-            Sy = jax.numpy.asarray(
-                rng.standard_normal((By, D), np.float32) * 0.1)
-            w = jax.numpy.asarray(rng.random(D, dtype=np.float32))
+            args = (jax.numpy.asarray(
+                rng.standard_normal((Bx, D), np.float32) * 0.1),
+                jax.numpy.asarray(
+                    rng.standard_normal((By, D), np.float32) * 0.1),
+                jax.numpy.asarray(rng.random(D, dtype=np.float32)))
             cands = [{"block_words": bw, "bx_tile": bx, "by_tile": by}
                      for bw in (128, 512)
-                     for bx in sorted({128, min(128, max(8, _bucket(Bx)))})
-                     for by in sorted({128, min(128, max(8, _bucket(By)))})]
+                     for bx in _lane_tiles(Bx) for by in _lane_tiles(By)]
             default = {"block_words": 512, "bx_tile": 128, "by_tile": 128}
 
-            def run(rec):
+            def run(rec, Sx, Sy, w):
                 return ops.gram(Sx, Sy, w, backend=backend,
                                 precision=precision, **rec)
         elif kind == "gram_ring":
@@ -303,41 +312,45 @@ def sweep_cell(kind: str, cell: dict, repeats: int = 3) -> dict:
                 return {}
             D = cell["D"]
             Bx, By = cell["Bx"] * P, cell["By"] * P  # cell keys = per-shard
-            Sx = jax.numpy.asarray(
-                rng.standard_normal((Bx, D), np.float32) * 0.1)
-            Sy = jax.numpy.asarray(
-                rng.standard_normal((By, D), np.float32) * 0.1)
-            w = jax.numpy.asarray(rng.random(D, dtype=np.float32))
+            args = (jax.numpy.asarray(
+                rng.standard_normal((Bx, D), np.float32) * 0.1),
+                jax.numpy.asarray(
+                    rng.standard_normal((By, D), np.float32) * 0.1),
+                jax.numpy.asarray(rng.random(D, dtype=np.float32)))
             cands = [{"block_words": bw, "bx_tile": bx, "by_tile": by}
                      for bw in (128, 512)
-                     for bx in sorted({128, min(128, max(8, _bucket(
-                         cell["Bx"])))})
-                     for by in sorted({128, min(128, max(8, _bucket(
-                         cell["By"])))})]
+                     for bx in _lane_tiles(cell["Bx"])
+                     for by in _lane_tiles(cell["By"])]
             default = {"block_words": 512, "bx_tile": 128, "by_tile": 128}
 
-            def run(rec):
+            def run(rec, Sx, Sy, w):
                 return ops.gram(Sx, Sy, w, backend=backend,
                                 precision=precision, **rec)
         else:
             return {}
         if default not in cands:
             cands.append(default)
+        feasible = []
         for rec in cands:
+            # timed jitted, as a training or serving step calls the kernels
+            step = jax.jit(functools.partial(run, rec))
+            fn = functools.partial(
+                lambda f: jax.block_until_ready(f(*args)), step)
             try:
-                t = _median_time(
-                    lambda: jax.block_until_ready(run(rec)), repeats)
+                fn()  # compile + warm
             except Exception:
                 continue  # infeasible candidate (e.g. invalid split)
-            timed.append((t, rec))
+            feasible.append((rec, fn))
+        timed = list(zip(_median_times([fn for _, fn in feasible], repeats),
+                         [rec for rec, _ in feasible]))
         if not any(rec == default for _, rec in timed):
             return {}  # even the default failed: leave the cell untuned
-        win = _pick(timed, default)
-        win = dict(win)
-        win["ms"] = round(min(t for t, r in timed if r == win) * 1e3, 4)
-        win["default_ms"] = round(
-            min(t for t, r in timed if r == default) * 1e3, 4)
-        return win
+        best = _pick(timed, default)
+        return dict(
+            best, **_device(),
+            ms=round(min(t for t, r in timed if r == best) * 1e3, 4),
+            default_ms=round(min(t for t, r in timed if r == default) * 1e3,
+                             4))
     except Exception as e:
         _warn_once(f"autotune sweep failed for {kind} cell {cell}: {e}")
         return {}
@@ -351,15 +364,11 @@ def sweep_cell(kind: str, cell: dict, repeats: int = 3) -> dict:
 
 _QUICK_GRID = [
     # (kind, cell) — paper-grid cells the benchmarks exercise
-    ("sig_trunc", dict(engine="pallas", d=6, depth=2, M=100, B=32,
-                       precision="fp32")),
-    ("sig_trunc", dict(engine="pallas", d=3, depth=3, M=200, B=32,
-                       precision="fp32")),
-    ("sig_trunc", dict(engine="pallas", d=6, depth=2, M=100, B=32,
-                       precision="bf16_fp32")),
-    ("sig_words", dict(engine="pallas", d=2, depth=3, M=100, B=32,
-                       precision="fp32")),
-    ("gram", dict(engine="pallas", D=364, Bx=64, By=64, precision="fp32")),
+    ("sig_trunc", dict(d=6, depth=2, M=100, B=32, precision="fp32")),
+    ("sig_trunc", dict(d=3, depth=3, M=200, B=32, precision="fp32")),
+    ("sig_trunc", dict(d=6, depth=2, M=100, B=32, precision="bf16_fp32")),
+    ("sig_words", dict(d=2, depth=3, M=100, B=32, precision="fp32")),
+    ("gram", dict(D=364, Bx=64, By=64, precision="fp32")),
 ]
 
 
@@ -378,8 +387,11 @@ def main(argv=None) -> int:
     grid = _QUICK_GRID  # --quick is the only shipped grid so far
     if not args.quick:
         print("note: only the --quick grid is defined; sweeping it")
+    engine = "pallas" if _device()["platform"] == "tpu" else \
+        "pallas_interpret"
     cells = load_cache()
     for kind, cell in grid:
+        cell = dict(cell, engine=engine)
         rec = sweep_cell(kind, cell, repeats=args.repeats)
         key = cell_key(kind, **cell)
         if rec:

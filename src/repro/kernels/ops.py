@@ -9,9 +9,10 @@ exactly one place.
 ``backend`` selection:
 
 - ``"jax"``      — pure-JAX levelwise Horner scan (works everywhere).
-- ``"pallas"``   — Pallas TPU kernels, compiled for the accelerator.
+- ``"pallas"``   — Pallas TPU kernels, compiled for the accelerator;
+                   raises when JAX's default backend is not a TPU.
 - ``"pallas_interpret"`` — same kernels executed in interpret mode (CPU
-                   validation; the container's default).
+                   validation of the kernels).
 - ``"auto"``     — pallas on TPU, jax elsewhere.
 - ``"hybrid"``   — projected sets only: dense levelwise Horner for all of
                    W_{<=N-1} plus per-word chains for the requested level-N
@@ -161,7 +162,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro import obs
@@ -414,11 +414,19 @@ def _on_tpu() -> bool:
 
 
 def resolve_backend(backend: str) -> tuple[str, bool]:
-    """backend string -> (engine, interpret)."""
+    """backend string -> (engine, interpret).  ``"pallas"`` never falls back:
+    without a TPU it raises, so no run mistakes interpret mode for the
+    compiled kernels."""
     if backend == "auto":
         return ("pallas", False) if _on_tpu() else ("jax", False)
     if backend == "pallas":
-        return "pallas", not _on_tpu()
+        if not _on_tpu():
+            raise RuntimeError(
+                "backend='pallas' compiles the kernels for a TPU, but JAX's "
+                f"default backend is {jax.default_backend()!r}; use "
+                "backend='pallas_interpret' to run the kernels in interpret "
+                "mode, or 'jax' for the reference engine")
+        return "pallas", False
     if backend == "pallas_interpret":
         return "pallas", True
     if backend == "jax":
@@ -429,6 +437,11 @@ def resolve_backend(backend: str) -> tuple[str, bool]:
 
 
 _resolve = resolve_backend  # back-compat alias
+
+
+def _tuned(engine: str, interpret: bool) -> str:
+    """The backend string an autotune cell is keyed and timed under."""
+    return "pallas_interpret" if interpret else engine
 
 
 def _check_backward(backward: str) -> None:
@@ -916,7 +929,7 @@ def _shard_wrap(mesh, names: tuple, with_lengths: bool, local_fn, *,
     """Wrap ``local_fn(increments, lengths_or_None)`` in shard_map with every
     argument batch-sharded on dim 0.  The body is the single-device dispatch,
     so the custom-VJP closure is rebuilt per shard and gradients shard
-    identically to the primals.  ``check_rep=False``: pallas_call has no
+    identically to the primals.  ``check_vma=False``: pallas_call has no
     replication rule.
 
     The wrapper is jitted (with retrace accounting under ``site``): the
@@ -934,8 +947,8 @@ def _shard_wrap(mesh, names: tuple, with_lengths: bool, local_fn, *,
             return local_fn(incs, None)
         in_specs = (spec,)
     return obs.instrument_jit(
-        shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=spec,
-                  check_rep=False), site=site)
+        jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=spec,
+                      check_vma=False), site=site)
 
 
 @plan_cache
@@ -1097,8 +1110,8 @@ def _gram_ring(mesh, names: tuple, size: int, engine: str, interpret: bool,
         return G
 
     return obs.instrument_jit(
-        shard_map(body, mesh=mesh, in_specs=(spec, spec, PartitionSpec()),
-                  out_specs=spec, check_rep=False), site="gram_ring")
+        jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, PartitionSpec()),
+                      out_specs=spec, check_vma=False), site="gram_ring")
 
 
 @_obs_entry
@@ -1138,14 +1151,15 @@ def gram(Sx: jax.Array, Sy: jax.Array, weights: jax.Array, *,
             # the per-shard ones — key the autotune cell on those (and on P:
             # the ring's step count changes the profitable block size)
             P = mb[2]
-            hit = autotune.lookup("gram_ring", engine=engine, D=Sx.shape[1],
-                                  Bx=-(-Sx.shape[0] // P),
+            hit = autotune.lookup("gram_ring",
+                                  engine=_tuned(engine, interpret),
+                                  D=Sx.shape[1], Bx=-(-Sx.shape[0] // P),
                                   By=-(-Sy.shape[0] // P), P=P,
                                   precision=precision)
         else:
-            hit = autotune.lookup("gram", engine=engine, D=Sx.shape[1],
-                                  Bx=Sx.shape[0], By=Sy.shape[0],
-                                  precision=precision)
+            hit = autotune.lookup("gram", engine=_tuned(engine, interpret),
+                                  D=Sx.shape[1], Bx=Sx.shape[0],
+                                  By=Sy.shape[0], precision=precision)
         block_words = hit.get("block_words", 512) if block_words is None \
             else block_words
         bx_tile = hit.get("bx_tile", 128) if bx_tile is None else bx_tile
@@ -1386,9 +1400,9 @@ def signature(increments: jax.Array, depth: int, *, backend: str = "auto",
     M_eff = transform_steps(spec, increments.shape[1]) if spec \
         else increments.shape[1]
     if batch_tile is None:
-        hit = autotune.lookup("sig_trunc", engine=engine, d=d_eff,
-                              depth=depth, M=M_eff, B=increments.shape[0],
-                              precision=precision)
+        hit = autotune.lookup("sig_trunc", engine=_tuned(engine, interpret),
+                              d=d_eff, depth=depth, M=M_eff,
+                              B=increments.shape[0], precision=precision)
         batch_tile = hit.get("batch_tile", 128)
         if split is None:
             split = hit.get("split")
@@ -1565,7 +1579,8 @@ def projected(increments: jax.Array, plan, *, backend: str = "auto",
         max_rows = max(p.closure_size for p in tplan.tiles)
     if batch_tile is None:
         hit = autotune.lookup(
-            "sig_words", engine=engine, d=wplan.d, depth=wplan.depth,
+            "sig_words", engine=_tuned(engine, interpret), d=wplan.d,
+            depth=wplan.depth,
             M=transform_steps(spec, increments.shape[1]) if spec
             else increments.shape[1],
             B=increments.shape[0], precision=precision)
@@ -1663,7 +1678,8 @@ def projected_forward_only(increments: jax.Array, plan, *,
         max_rows = max(p.closure_size for p in tplan.tiles)
     if batch_tile is None:
         hit = autotune.lookup(
-            "sig_words", engine=engine, d=wplan.d, depth=wplan.depth,
+            "sig_words", engine=_tuned(engine, interpret), d=wplan.d,
+            depth=wplan.depth,
             M=transform_steps(spec, increments.shape[1]) if spec
             else increments.shape[1],
             B=increments.shape[0], precision=precision)

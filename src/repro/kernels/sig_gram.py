@@ -36,20 +36,25 @@ def _kernel(sx_ref, sy_ref, w_ref, out_ref):
     sxw = sx_ref[...] * w_ref[...]          # (bx, kt) * (1, kt): fused ω
     out_ref[...] += jax.lax.dot_general(    # contract the word block on MXU
         sxw, sy_ref[...], dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # f32, not one bf16 pass
         preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bx_tile", "by_tile", "k_tile",
                                              "interpret"))
 def sig_gram_tiles(Sx: jax.Array, Sy: jax.Array, weights: jax.Array, *,
-                   bx_tile: int = 128, by_tile: int = 128, k_tile: int = 512,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool, bx_tile: int = 128, by_tile: int = 128,
+                   k_tile: int = 512) -> jax.Array:
     """Weighted Gram of signature coordinate matrices.
 
     Sx: (B_x, D), Sy: (B_y, D), weights: (D,)  ->  (B_x, B_y) float32 with
-    G[i, j] = Σ_k Sx[i, k] · weights[k] · Sy[j, k].
+    G[i, j] = Σ_k Sx[i, k] · weights[k] · Sy[j, k].  ``bx_tile`` and
+    ``by_tile`` follow the lane-tile rule of
+    :func:`repro.kernels.sig_trunc.lane_tile`; ``interpret=True`` runs in
+    Pallas interpret mode, ``False`` compiles for the TPU.
     """
     from repro import obs
+    from repro.kernels.sig_trunc import lane_tile
     obs.count_trace("sig_gram_tiles", Sx, Sy, bx_tile=bx_tile,
                     by_tile=by_tile, k_tile=k_tile)
     Bx, D = Sx.shape
@@ -57,10 +62,10 @@ def sig_gram_tiles(Sx: jax.Array, Sy: jax.Array, weights: jax.Array, *,
     if D2 != D or weights.shape != (D,):
         raise ValueError(f"shape mismatch: Sx {Sx.shape}, Sy {Sy.shape}, "
                          f"weights {weights.shape}")
-    bx = min(bx_tile, _round_up(Bx, 8))
-    by = min(by_tile, _round_up(By, 8))
-    kt = min(k_tile, _round_up(D, 128))
-    Bx_p, By_p, D_p = _round_up(Bx, bx), _round_up(By, by), _round_up(D, kt)
+    bx, Bx_p = lane_tile(Bx, bx_tile)
+    by, By_p = lane_tile(By, by_tile)
+    kt = min(_round_up(k_tile, 128), _round_up(D, 128))  # lane axis
+    D_p = _round_up(D, kt)
     x = jnp.pad(Sx, ((0, Bx_p - Bx), (0, D_p - D))).astype(jnp.float32)
     y = jnp.pad(Sy, ((0, By_p - By), (0, D_p - D))).astype(jnp.float32)
     w = jnp.pad(weights.astype(jnp.float32), (0, D_p - D))[None, :]

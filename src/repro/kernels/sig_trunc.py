@@ -60,7 +60,8 @@ def state_footprint(d: int, depth: int, s: int, batch_tile: int,
     is the element byte width of the state dtype — 4 for fp32, 2 for bf16 —
     so VMEM budgeting stays correct under mixed precision."""
     rows = max(0, s - 1) + cone_rows(d, depth, s)
-    return (rows + d ** (depth - s)) * batch_tile * itemsize
+    lanes = -(-batch_tile // 128) * 128  # VMEM pads the lane axis to 128
+    return (rows + d ** (depth - s)) * lanes * itemsize
 
 
 def choose_split(d: int, depth: int, batch_tile: int,
@@ -70,6 +71,67 @@ def choose_split(d: int, depth: int, batch_tile: int,
         if state_footprint(d, depth, s, batch_tile, itemsize) <= vmem_budget:
             return s
     return depth - 1
+
+
+# Scoped-VMEM limit the kernels compile with: v4, v5e, v5p and v6e hold
+# 128 MiB of VMEM per TensorCore, and Mosaic keeps the rest for its own
+# scratch.  Mosaic's default limit (16 MiB on v5e) refuses the pipelined
+# blocks of long streamed paths that do fit.
+VMEM_LIMIT_BYTES = 100 * 2**20
+
+
+def compiler_params():
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def block_bytes(shape, itemsize: int = 4) -> int:
+    """VMEM bytes of one block: its two minor dims are padded to the
+    (8, 128) tile, 16 sublanes for 2-byte dtypes."""
+    *lead, r, c = shape
+    sub = 8 * 4 // itemsize
+    return (math.prod(lead) * (-(-r // sub) * sub) * (-(-c // 128) * 128)
+            * itemsize)
+
+
+def check_vmem(kernel: str, need: int, what: str) -> None:
+    """Raise when a kernel's VMEM blocks cannot fit the scoped limit."""
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"{kernel}: {what} needs {need / 2**20:.1f} MiB of VMEM, over "
+            f"the {VMEM_LIMIT_BYTES / 2**20:.0f} MiB limit.  The kernel holds "
+            "the whole time axis in VMEM; cut the path into windows, or for "
+            "stream=True raise stream_stride")
+
+
+def lane_tile(B: int, batch_tile: int) -> tuple[int, int]:
+    """-> (tile, B_pad) for a batch on the 128-wide lane axis.  Mosaic takes
+    a lane block only as a multiple of 128 or as the whole padded axis, so
+    the requested tile rounds up to a multiple of 128, and a tile at least
+    as wide as the batch shrinks to the batch padded to 8."""
+    full = max(8, -(-B // 8) * 8)
+    tile = -(-batch_tile // 128) * 128
+    if tile >= full:
+        return full, full
+    return tile, -(-B // tile) * tile
+
+
+def vmem_footprint(M: int, d_raw: int, d: int, depth: int, s: int,
+                   batch_tile: int, *, M_out: int = 0, in_itemsize: int = 4,
+                   out_itemsize: int = 4, buffers: int = 2) -> int:
+    """VMEM bytes of one grid step at split ``s``: the (M, d_raw) increment
+    block and the output block (the (M_out, rows) emission block when
+    streamed), each ``buffers``-deep, plus the streamed state scratch and
+    the top cone level's chain temporary."""
+    rows = max(0, s - 1) + cone_rows(d, depth, s)
+    blocks = block_bytes((M, d_raw, batch_tile), in_itemsize)
+    if M_out:
+        blocks += block_bytes((M_out, rows, batch_tile), out_itemsize)
+        scratch = block_bytes((rows, batch_tile))
+    else:
+        blocks += block_bytes((rows, batch_tile))
+        scratch = 0
+    return (buffers * blocks + scratch
+            + block_bytes((d ** (depth - s), batch_tile)))
 
 
 def _kernel(incs_ref, *refs, d: int, depth: int, s: int, M: int,
@@ -108,12 +170,17 @@ def _kernel(incs_ref, *refs, d: int, depth: int, s: int, M: int,
     letters = [(c // d ** (s - 1 - k)) % d for k in range(s)]
 
     state_ref[...] = jnp.zeros(state_ref.shape, state_ref.dtype)
+    # one-hot row masks of the prefix letters: Mosaic has no dynamic_slice
+    # on values, so ΔX^{(u_k)} is a masked sublane sum over the d channels
+    channel = jax.lax.broadcasted_iota(jnp.int32, (d, state_ref.shape[1]), 0)
+    letter_mask = [(channel == letters[k]).astype(state_ref.dtype)
+                   for k in range(s)]
 
     def update(dx):
         """One augmented-increment Horner update.  dx: (d, B) in state dtype."""
         B = dx.shape[-1]
         # per-path-step increment components ΔX^{(u_k)}  -> (1, B)
-        dxl = [jax.lax.dynamic_slice(dx, (letters[k], 0), (1, B))
+        dxl = [jnp.sum(dx * letter_mask[k], axis=0, keepdims=True)
                for k in range(s)]
 
         def path_val(lev):  # old value of ancestor u_{1:lev}, lev in 1..s-1
@@ -172,8 +239,8 @@ def _kernel(incs_ref, *refs, d: int, depth: int, s: int, M: int,
                 def _emit():
                     # the emission buffer may be bf16 (precision="bf16_fp32"):
                     # round on store, the fp32 accumulator state is untouched
-                    pl.store(out_ref, (pl.ds(q, 1), slice(None), slice(None)),
-                             state_ref[...].astype(out_ref.dtype)[None])
+                    out_ref[pl.ds(q, 1), :, :] = \
+                        state_ref[...].astype(out_ref.dtype)[None]
         return 0
 
     jax.lax.fori_loop(0, M, body, 0)
@@ -240,8 +307,8 @@ def _storage_dtype(precision: str):
                                              "interpret", "vmem_budget",
                                              "stream", "stream_stride",
                                              "transform", "precision"))
-def sig_trunc(increments: jax.Array, depth: int, *, batch_tile: int = 128,
-              split: int | None = None, interpret: bool = True,
+def sig_trunc(increments: jax.Array, depth: int, *, interpret: bool,
+              batch_tile: int = 128, split: int | None = None,
               vmem_budget: int = 6 * 2**20, stream: bool = False,
               stream_stride: int = 1, transform=None, taux=None,
               precision: str = "fp32") -> jax.Array:
@@ -258,6 +325,12 @@ def sig_trunc(increments: jax.Array, depth: int, *, batch_tile: int = 128,
     the (B, 2) ``transform_time_aux`` array, required iff the transform has
     a time channel.  ``precision="bf16_fp32"`` stores the increments block
     in bf16 (halved VMEM/HBM traffic) with fp32 accumulators.
+
+    ``split=None`` takes the smallest split whose state fits ``vmem_budget``
+    and, when streamed, whose emission block fits the VMEM limit; a shape
+    whose blocks fit at no split raises ``ValueError``.  ``interpret=True``
+    runs the kernel in Pallas interpret mode (any backend), ``False``
+    compiles it for the TPU.
     """
     obs.count_trace("sig_trunc", increments, depth=depth,
                     batch_tile=batch_tile, split=split, stream=stream,
@@ -274,14 +347,32 @@ def sig_trunc(increments: jax.Array, depth: int, *, batch_tile: int = 128,
     sub = 2 if fuse_ll else 1
     d = (2 * d_raw if fuse_ll else d_raw) + (1 if fuse_time else 0)
     M_aug = M * sub
-    s = choose_split(d, depth, batch_tile, vmem_budget) if split is None else split
+    M_out = -(-M_aug // stream_stride) if stream else 0
+    batch_tile, B_pad = lane_tile(B, batch_tile)
+    in_itemsize = jnp.dtype(_storage_dtype(precision)).itemsize
+
+    def need(s):  # Pallas double-buffers blocks unless the grid is 1 step
+        steps = B_pad // batch_tile * d**s
+        return vmem_footprint(M, d_raw, d, depth, s, batch_tile, M_out=M_out,
+                              in_itemsize=in_itemsize,
+                              out_itemsize=in_itemsize if stream else 4,
+                              buffers=1 if steps == 1 else 2)
+
+    if split is None:
+        s = choose_split(d, depth, batch_tile, vmem_budget)
+        while s < depth - 1 and need(s) > VMEM_LIMIT_BYTES:
+            s += 1
+    else:
+        s = split
     if not 0 <= s < depth:
         raise ValueError(f"split {s} outside [0, {depth})")
+    check_vmem("sig_trunc", need(s),
+               f"(M={M}, d={d_raw}, depth={depth}, split={s}, "
+               f"batch_tile={batch_tile}, M_out={M_out})")
     n_cells = d**s
     n_path = max(0, s - 1)
     rows = n_path + cone_rows(d, depth, s)
 
-    B_pad = -(-B // batch_tile) * batch_tile
     x = jnp.moveaxis(increments, 0, -1)  # (M, d_raw, B)
     x = jnp.pad(x, ((0, 0), (0, 0), (0, B_pad - B))).astype(
         _storage_dtype(precision))
@@ -296,33 +387,36 @@ def sig_trunc(increments: jax.Array, depth: int, *, batch_tile: int = 128,
         inputs.append(ta)
         in_specs.append(pl.BlockSpec((2, batch_tile), lambda bi, c: (0, bi)))
 
+    # the cell axis is a squeezed block dim, so each block's trailing
+    # (rows, batch_tile) dims span the whole row extent: Mosaic accepts any
+    # row count there, where a (n_cells * rows)-row array would need rows % 8
     if not stream:
         out = pl.pallas_call(
             kern,
             grid=(B_pad // batch_tile, n_cells),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((rows, batch_tile), lambda bi, c: (c, bi)),
-            out_shape=jax.ShapeDtypeStruct((n_cells * rows, B_pad),
+            out_specs=pl.BlockSpec((None, rows, batch_tile),
+                                   lambda bi, c: (c, 0, bi)),
+            out_shape=jax.ShapeDtypeStruct((n_cells, rows, B_pad),
                                            jnp.float32),
+            compiler_params=compiler_params(),
             interpret=interpret,
         )(*inputs)
-        out = out.reshape(n_cells, rows, B_pad)
         return _reassemble(out, d, depth, s, B).astype(increments.dtype)
 
-    M_out = -(-M_aug // stream_stride)
     out = pl.pallas_call(
         functools.partial(kern, stream_stride=stream_stride),
         grid=(B_pad // batch_tile, n_cells),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((M_out, rows, batch_tile),
-                               lambda bi, c: (0, c, bi)),
+        out_specs=pl.BlockSpec((M_out, None, rows, batch_tile),
+                               lambda bi, c: (0, c, 0, bi)),
         # bf16_fp32: the (M_out, ·, ·) emission buffer is stored at the
         # precision's storage dtype (halving its VMEM/HBM footprint) while
         # the running state scratch stays a full fp32 accumulator
-        out_shape=jax.ShapeDtypeStruct((M_out, n_cells * rows, B_pad),
+        out_shape=jax.ShapeDtypeStruct((M_out, n_cells, rows, B_pad),
                                        _storage_dtype(precision)),
         scratch_shapes=[pltpu.VMEM((rows, batch_tile), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*inputs)
-    out = out.reshape(M_out, n_cells, rows, B_pad)
     return _reassemble_stream(out, d, depth, s, B).astype(increments.dtype)

@@ -25,6 +25,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.words import TiledPlan, WordPlan, make_tiled_plan
 
 
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def _tile_tables(plan: WordPlan, W_pad: int, depth_pad: int):
     """One-hot tables for a tile, padded to (depth_pad, W_pad, ...) ."""
     W = plan.closure_size
@@ -77,8 +80,9 @@ def _kernel(incs_ref, p_ref, l_ref, inv_ref, emit_ref, *refs,
     B = state_ref.shape[1]
     sub = 2 if fuse_ll else 1
     M_aug = M * sub
-    init = jnp.zeros((W1, B), state_ref.dtype).at[0, :].set(1.0)  # S[eps] = 1
-    state_ref[...] = init
+    # S[eps] = 1 on row 0: an iota compare, since Mosaic has no scatter
+    row = jax.lax.broadcasted_iota(jnp.int32, (W1, B), 0)
+    state_ref[...] = (row == 0).astype(state_ref.dtype)
 
     def body(j, _):
         g = incs_ref[pl.ds(j, 1), :, :][0].astype(state_ref.dtype)  # (d_raw, B)
@@ -98,9 +102,12 @@ def _kernel(incs_ref, p_ref, l_ref, inv_ref, emit_ref, *refs,
             acc = jnp.zeros((W1 - 1, B), S.dtype)
             h = acc
             for jj in range(depth):                 # Horner steps (Alg. 1)
-                pfx = jnp.dot(p_ref[0, jj], S,      # one-hot gather on MXU
+                # one-hot gathers on the MXU; HIGHEST keeps every f32 bit
+                # (one bf16 pass would round S to 8 mantissa bits)
+                pfx = jnp.dot(p_ref[0, jj], S, precision=_EXACT,
                               preferred_element_type=S.dtype)
-                dxl = jnp.dot(l_ref[0, jj], dx, preferred_element_type=S.dtype)
+                dxl = jnp.dot(l_ref[0, jj], dx, precision=_EXACT,
+                              preferred_element_type=S.dtype)
                 acc = (pfx + acc) * dxl * inv_ref[0, jj][:, None]
                 h = h + acc * emit_ref[0, jj][:, None]
             state_ref[1:, :] = S[1:, :] + h
@@ -111,8 +118,8 @@ def _kernel(incs_ref, p_ref, l_ref, inv_ref, emit_ref, *refs,
                 def _emit():
                     # bf16 emission buffer under bf16_fp32: round on store,
                     # the fp32 accumulator state is untouched
-                    pl.store(out_ref, (pl.ds(q, 1), slice(None), slice(None)),
-                             state_ref[...].astype(out_ref.dtype)[None])
+                    out_ref[pl.ds(q, 1), :, :] = \
+                        state_ref[...].astype(out_ref.dtype)[None]
         return 0
 
     jax.lax.fori_loop(0, M, body, 0)
@@ -121,10 +128,10 @@ def _kernel(incs_ref, p_ref, l_ref, inv_ref, emit_ref, *refs,
 @functools.partial(jax.jit, static_argnames=("tplan", "batch_tile", "interpret",
                                              "stream", "stream_stride",
                                              "transform", "precision"))
-def sig_words(increments: jax.Array, tplan: TiledPlan, *,
-              batch_tile: int = 128, interpret: bool = True,
-              stream: bool = False, stream_stride: int = 1, transform=None,
-              taux=None, precision: str = "fp32") -> jax.Array:
+def sig_words(increments: jax.Array, tplan: TiledPlan, *, interpret: bool,
+              batch_tile: int = 128, stream: bool = False,
+              stream_stride: int = 1, transform=None, taux=None,
+              precision: str = "fp32") -> jax.Array:
     """Projected signature via the Pallas tile kernel.
 
     increments: (B, M, d)  ->  (B, |I|) coefficients in tplan.words order.
@@ -137,10 +144,14 @@ def sig_words(increments: jax.Array, tplan: TiledPlan, *,
     (``tplan.d == transform_dim(transform, d_raw)``); ``taux`` is the (B, 2)
     ``transform_time_aux`` array, required iff the transform has a time
     channel.  ``precision="bf16_fp32"`` stores the increments block in bf16
-    with fp32 accumulation.
+    with fp32 accumulation.  ``interpret=True`` runs the kernel in Pallas
+    interpret mode, ``False`` compiles it for the TPU; blocks that cannot
+    fit VMEM raise ``ValueError``.
     """
     from repro import obs
-    from repro.kernels.sig_trunc import _fuse_flags, _storage_dtype
+    from repro.kernels.sig_trunc import (_fuse_flags, _storage_dtype,
+                                         block_bytes, check_vmem,
+                                         compiler_params, lane_tile)
     obs.count_trace("sig_words", increments, tiles=len(tplan.tiles),
                     batch_tile=batch_tile, stream=stream,
                     precision=precision)
@@ -169,7 +180,21 @@ def sig_words(increments: jax.Array, tplan: TiledPlan, *,
     invt = jnp.asarray(np.stack(invs))  # (T, depth, W)
     emitt = jnp.asarray(np.stack(emits))
 
-    B_pad = -(-B // batch_tile) * batch_tile
+    batch_tile, B_pad = lane_tile(B, batch_tile)
+    M_out = -(-M_aug // stream_stride) if stream else 0
+    in_itemsize = jnp.dtype(_storage_dtype(precision)).itemsize
+    W1 = 1 + W_pad
+    tables = (block_bytes((depth, W_pad, W1)) + block_bytes((depth, W_pad, d))
+              + 2 * block_bytes((depth, W_pad)))
+    out_block = (block_bytes((M_out, W1, batch_tile), in_itemsize)
+                 + block_bytes((W1, batch_tile)) if stream
+                 else block_bytes((W1, batch_tile)))
+    buffers = 1 if B_pad // batch_tile * T == 1 else 2
+    check_vmem("sig_words",
+               buffers * (block_bytes((M, d_raw, batch_tile), in_itemsize)
+                          + tables + out_block),
+               f"(M={M}, d={d_raw}, depth={depth}, tile rows={W1}, "
+               f"batch_tile={batch_tile}, M_out={M_out})")
     x = jnp.moveaxis(increments, 0, -1)
     x = jnp.pad(x, ((0, 0), (0, 0), (0, B_pad - B))).astype(
         _storage_dtype(precision))
@@ -193,35 +218,36 @@ def sig_words(increments: jax.Array, tplan: TiledPlan, *,
     row_idx = jnp.asarray(
         [tiles[t].out_rows[k] for t, k in tplan.gather], dtype=jnp.int32)
 
+    # the tile axis is a squeezed block dim (see sig_trunc): each block's
+    # trailing (1 + W_pad, batch_tile) dims span the whole row extent
     if not stream:
         out = pl.pallas_call(
             kern,
             grid=(B_pad // batch_tile, T),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1 + W_pad, batch_tile),
-                                   lambda bi, t: (t, bi)),
-            out_shape=jax.ShapeDtypeStruct((T * (1 + W_pad), B_pad),
+            out_specs=pl.BlockSpec((None, 1 + W_pad, batch_tile),
+                                   lambda bi, t: (t, 0, bi)),
+            out_shape=jax.ShapeDtypeStruct((T, 1 + W_pad, B_pad),
                                            jnp.float32),
+            compiler_params=compiler_params(),
             interpret=interpret,
         )(*inputs)
-        out = out.reshape(T, 1 + W_pad, B_pad)
         vals = out[tile_idx, row_idx, :B]   # (n_words, B)
         return vals.T.astype(increments.dtype)
 
-    M_out = -(-M_aug // stream_stride)
     out = pl.pallas_call(
         functools.partial(kern, stream_stride=stream_stride),
         grid=(B_pad // batch_tile, T),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((M_out, 1 + W_pad, batch_tile),
-                               lambda bi, t: (0, t, bi)),
+        out_specs=pl.BlockSpec((M_out, None, 1 + W_pad, batch_tile),
+                               lambda bi, t: (0, t, 0, bi)),
         # bf16_fp32: streamed emission buffer at the storage dtype, fp32
         # accumulator scratch (same discipline as sig_trunc's stream cell)
-        out_shape=jax.ShapeDtypeStruct((M_out, T * (1 + W_pad), B_pad),
+        out_shape=jax.ShapeDtypeStruct((M_out, T, 1 + W_pad, B_pad),
                                        _storage_dtype(precision)),
         scratch_shapes=[pltpu.VMEM((1 + W_pad, batch_tile), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*inputs)
-    out = out.reshape(M_out, T, 1 + W_pad, B_pad)
     vals = out[:, tile_idx, row_idx, :B]    # (M_out, n_words, B)
     return jnp.moveaxis(vals, -1, 0).astype(increments.dtype)

@@ -6,12 +6,21 @@ jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: tuple, axes: tuple, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules and
+    ``shard_map`` bodies place data, so no op has to carry an explicit
+    ``out_sharding`` (JAX 0.9 makes Explicit axes by default)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_dev_mesh(data: int = 1, model: int = 1):
@@ -30,7 +39,7 @@ def make_dev_mesh(data: int = 1, model: int = 1):
             f"{data * model} devices but only {n} are visible — launch with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={data * model}"
             f" (CPU) or shrink the mesh")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def make_sig_mesh(batch: int | None = None):
@@ -52,4 +61,4 @@ def make_sig_mesh(batch: int | None = None):
             f"{n} are visible — launch with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={batch} (CPU)"
             f" or shrink the axis")
-    return jax.make_mesh((batch,), ("data",))
+    return auto_mesh((batch,), ("data",))

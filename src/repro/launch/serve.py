@@ -17,10 +17,12 @@ import jax.numpy as jnp
 import repro.models as M
 from repro.checkpoint import Checkpointer, latest_step
 from repro.configs import get_config, reduce_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import ServeEngine
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -29,6 +29,8 @@ from repro.data.pipeline import ShardedLoader, TokenStream
 from repro.distributed.ctx import sharding_ctx
 from repro.distributed.sharding import batch_specs, opt_state_specs, \
     param_specs
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import auto_mesh
 from repro.optim import adafactor, adamw, linear_warmup_cosine
 from repro.train import make_train_step
 
@@ -42,10 +44,11 @@ def parse_mesh(spec: str):
                          f"compile-only validation of production meshes)")
     axes = {1: ("data",), 2: ("data", "model"),
             3: ("pod", "data", "model")}[len(dims)]
-    return jax.make_mesh(dims, axes)
+    return auto_mesh(dims, axes)
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",

@@ -136,8 +136,6 @@ def record_cost(site: str, fn, *args, **kwargs) -> dict:
     try:
         compiled = jfn.lower(*args, **kwargs).compile()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-            ca = ca[0] if ca else {}
     except Exception:
         ca = {}
     flops = float(ca.get("flops", 0.0) or 0.0)

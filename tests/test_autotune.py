@@ -104,6 +104,24 @@ def test_cell_key_buckets_sizes():
     assert d_ != a           # structural axes are exact
 
 
+def test_cell_key_separates_devices(cache, monkeypatch):
+    """A record swept on one device is never applied on another: the key
+    carries the platform and device kind of the device it was timed on."""
+    cell = dict(CELL, engine="pallas")
+    cpu = autotune.cell_key("sig_trunc", platform="cpu", device_kind="cpu",
+                            **cell)
+    tpu = autotune.cell_key("sig_trunc", platform="tpu",
+                            device_kind="TPU v5 lite", **cell)
+    assert cpu != tpu
+    _write(cache, {"version": 1, "cells": {cpu: {"batch_tile": 256}}})
+    monkeypatch.setattr(autotune, "_device", lambda: {
+        "platform": "tpu", "device_kind": "TPU v5 lite"})
+    assert autotune.lookup("sig_trunc", **cell) == {}
+    monkeypatch.setattr(autotune, "_device", lambda: {
+        "platform": "cpu", "device_kind": "cpu"})
+    assert autotune.lookup("sig_trunc", **cell)["batch_tile"] == 256
+
+
 def test_hysteresis_keeps_default_within_noise():
     """A non-default candidate must beat the default by >= 10%, so the tuned
     configuration can never lose to the default by more than that margin."""
@@ -138,6 +156,7 @@ def test_sweep_mode_persists_winner(cache, monkeypatch):
                 precision="fp32")
     rec = autotune.lookup("sig_trunc", **cell)
     assert "batch_tile" in rec
+    assert rec["platform"] == autotune._device()["platform"]
     saved = json.loads(cache.read_text())
     assert autotune.cell_key("sig_trunc", **cell) in saved["cells"]
     # second lookup is a pure cache hit (load mode suffices)

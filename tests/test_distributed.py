@@ -18,12 +18,13 @@ _SCRIPT = textwrap.dedent("""
     from repro.distributed.sharding import batch_specs, cache_specs, \\
         opt_state_specs, param_specs
     from repro.distributed.ctx import sharding_ctx
+    from repro.launch.mesh import make_dev_mesh
     from repro.optim import adamw
     from repro.serve import make_serve_step
     from repro.train import make_train_step
 
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_dev_mesh(data=2, model=4)
     cfg = reduce_config(get_config("{arch}"))
     rules = {{}}
 

@@ -47,6 +47,30 @@ def test_sig_trunc_batch_tile_padding():
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("B,tile,want", [
+    (5, 2, (8, 8)), (5, 128, (8, 8)), (130, 8, (128, 256)),
+    (130, 128, (128, 256)), (1024, 256, (256, 1024)), (200, 256, (200, 200)),
+])
+def test_lane_tile_is_a_multiple_of_128_or_the_whole_batch(B, tile, want):
+    from repro.kernels.sig_trunc import lane_tile
+    got = lane_tile(B, tile)
+    assert got == want
+    assert got[0] % 128 == 0 or got[0] == got[1]
+
+
+def test_sig_trunc_blocks_over_vmem_raise():
+    """A path whose VMEM blocks fit at no split fails with a clear error at
+    trace time, before any compile."""
+    x = jax.ShapeDtypeStruct((128, 40_000, 6), jnp.float32)
+    with pytest.raises(ValueError, match="MiB of VMEM"):
+        jax.eval_shape(lambda a: sig_trunc(a, 3, interpret=True), x)
+    # streamed: the split rises until the emission block fits
+    y = jax.ShapeDtypeStruct((32, 2048, 4), jnp.float32)
+    out = jax.eval_shape(lambda a: sig_trunc(a, 4, interpret=True,
+                                             stream=True), y)
+    assert out.shape == (32, 2048, 340)
+
+
 def test_sig_trunc_bf16():
     x = _incs(2, 2, 6, 3).astype(jnp.bfloat16)
     got = sig_trunc(x, 3, batch_tile=8, interpret=True)  # f32 accumulation
