@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import tensor_ops as tops
+from .signature import COTANGENT_SCOPE, RECONSTRUCT_SCOPE, scoped_vjp
 from .words import Word, encode, level_offsets, sig_dim
 
 
@@ -80,12 +81,8 @@ def _make_hybrid(d: int, depth: int, top_words: tuple[Word, ...]):
                                         jnp.moveaxis(increments, 1, 0))
         return jnp.concatenate([tops.levels_to_flat(levels), top], axis=1)
 
-    @jax.custom_vjp
-    def hybrid(increments):
-        return scan(increments)
-
     def fwd(increments):
-        out = hybrid(increments)
+        out = scan(increments)
         return out, (increments, out)
 
     def bwd(res, g):
@@ -102,11 +99,14 @@ def _make_hybrid(d: int, depth: int, top_words: tuple[Word, ...]):
 
         def body(carry, dx):
             (S, T), (Gl, Gt) = carry
-            S_prev = tops.horner_step(S, -dx)             # Prop. 4.6
-            flat_prev = tops.levels_to_flat(S_prev)
-            T_prev = T - _top_increment(flat_prev, dx, letters, pidx, depth)
-            _, vjp_fn = jax.vjp(step_fn, S_prev, T_prev, dx)
-            Gl_p, Gt_p, g_dx = vjp_fn((Gl, Gt))
+            with jax.named_scope(RECONSTRUCT_SCOPE):
+                S_prev = tops.horner_step(S, -dx)         # Prop. 4.6
+                flat_prev = tops.levels_to_flat(S_prev)
+                T_prev = T - _top_increment(flat_prev, dx, letters, pidx,
+                                            depth)
+            with jax.named_scope(COTANGENT_SCOPE):
+                _, vjp_fn = jax.vjp(step_fn, S_prev, T_prev, dx)
+                Gl_p, Gt_p, g_dx = vjp_fn((Gl, Gt))
             return ((S_prev, T_prev), (Gl_p, Gt_p)), g_dx
 
         (_, _), g_rev = jax.lax.scan(body, ((S_lv, S_top), (G_lv, G_top)),
@@ -114,8 +114,7 @@ def _make_hybrid(d: int, depth: int, top_words: tuple[Word, ...]):
                                      reverse=True)
         return (jnp.moveaxis(g_rev, 0, 1),)
 
-    hybrid.defvjp(fwd, bwd)
-    return hybrid
+    return scoped_vjp(scan, fwd, bwd)
 
 
 def hybrid_low_plus_top(increments: jax.Array, top_words, depth: int,

@@ -16,9 +16,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .signature import (_fold_chunks, _subsample_stream, as_lengths,
-                        default_chunk, mask_increments, stream_emit_mask,
-                        stream_emit_steps, unsupported_stream_backward)
+from .signature import (COTANGENT_SCOPE, PROJ_INDEX_SCOPE,
+                        RECONSTRUCT_SCOPE, _fold_chunks, _subsample_stream,
+                        as_lengths, default_chunk, mask_increments,
+                        scoped_vjp, stream_emit_mask, stream_emit_steps,
+                        unsupported_stream_backward)
 from .words import WordPlan, make_plan
 from . import tensor_ops as tops
 
@@ -41,8 +43,9 @@ def projected_step(S: jax.Array, dx: jax.Array, prefix_idx, letters, inv,
     acc = jnp.zeros((B, prefix_idx.shape[0]), S.dtype)
     h = acc
     for j in range(depth):  # static unroll over Horner steps
-        pfx = jnp.take(S, prefix_idx[:, j], axis=1)       # S_old[w_{1:j}]
-        dxl = jnp.take(dx, letters[:, j], axis=1)         # ΔX^(i_{j+1})
+        with jax.named_scope(PROJ_INDEX_SCOPE):
+            pfx = jnp.take(S, prefix_idx[:, j], axis=1)   # S_old[w_{1:j}]
+            dxl = jnp.take(dx, letters[:, j], axis=1)     # ΔX^(i_{j+1})
         acc = (pfx + acc) * dxl * inv[None, :, j]         # /(n - j)
         h = h + acc * emit[None, :, j]                    # collect at j = n-1
     return S.at[:, 1:].add(h)
@@ -90,9 +93,11 @@ def projected_inverse_bwd_scan(increments: jax.Array, S_T: jax.Array,
 
     def step(carry, dx):
         S, G = carry
-        S_prev = step_fn(S, -dx)
-        _, vjp_fn = jax.vjp(step_fn, S_prev, dx)
-        G_prev, g_dx = vjp_fn(G)
+        with jax.named_scope(RECONSTRUCT_SCOPE):
+            S_prev = step_fn(S, -dx)
+        with jax.named_scope(COTANGENT_SCOPE):
+            _, vjp_fn = jax.vjp(step_fn, S_prev, dx)
+            G_prev, g_dx = vjp_fn(G)
         return (S_prev, G_prev), g_dx
 
     (_, _), g_rev = jax.lax.scan(step, (S_T, G_T),
@@ -108,7 +113,6 @@ def _make_projected_vjp(plan: WordPlan):
     def step_fn(S, dx):
         return projected_step(S, dx, *tables)
 
-    @jax.custom_vjp
     def proj(increments):
         return _scan_projected(increments, plan, stream=False)
 
@@ -127,8 +131,7 @@ def _make_projected_vjp(plan: WordPlan):
         increments, S_T = res
         return (projected_inverse_bwd_scan(increments, S_T, g_out, plan),)
 
-    proj.defvjp(fwd, bwd)
-    return proj
+    return scoped_vjp(proj, fwd, bwd)
 
 
 def projected_stream_inverse_bwd_scan(increments: jax.Array, S_T: jax.Array,
@@ -161,10 +164,11 @@ def projected_stream_inverse_bwd_scan(increments: jax.Array, S_T: jax.Array,
     def step(carry, xs):
         S, G = carry
         dx, g_j = xs
-        G = G + g_j
-        S_prev = step_fn(S, -dx)
-        _, vjp_fn = jax.vjp(step_fn, S_prev, dx)
-        G_prev, g_dx = vjp_fn(G)
+        with jax.named_scope(RECONSTRUCT_SCOPE):
+            S_prev = step_fn(S, -dx)
+        with jax.named_scope(COTANGENT_SCOPE):
+            _, vjp_fn = jax.vjp(step_fn, S_prev, dx)
+            G_prev, g_dx = vjp_fn(G + g_j)
         return (S_prev, G_prev), g_dx
 
     (_, _), g_rev = jax.lax.scan(step, (S_T, jnp.zeros_like(S_T)),
@@ -177,7 +181,6 @@ def projected_stream_inverse_bwd_scan(increments: jax.Array, S_T: jax.Array,
 def _make_projected_stream_vjp(plan: WordPlan, stride: int):
     tables = _plan_tables(plan)
 
-    @jax.custom_vjp
     def proj(increments):
         return _scan_projected(increments, plan, stream=True,
                                stream_stride=stride)
@@ -200,8 +203,7 @@ def _make_projected_stream_vjp(plan: WordPlan, stride: int):
         return (projected_stream_inverse_bwd_scan(increments, S_T, g_steps,
                                                   plan, stride),)
 
-    proj.defvjp(fwd, bwd)
-    return proj
+    return scoped_vjp(proj, fwd, bwd)
 
 
 @lru_cache(maxsize=None)
@@ -220,7 +222,6 @@ def _make_projected_checkpoint_vjp(plan: WordPlan, chunk: int):
     def fold(increments):
         return _fold_chunks(increments, chunk)
 
-    @jax.custom_vjp
     def proj(increments):
         return _scan_projected(increments, plan, stream=False)
 
@@ -246,16 +247,16 @@ def _make_projected_checkpoint_vjp(plan: WordPlan, chunk: int):
 
         def outer(G, xs):
             bound, c_incs = xs
-            _, vjp_fn = jax.vjp(chunk_fn, bound, c_incs)
-            G_prev, g_incs = vjp_fn(G)
+            with jax.named_scope(COTANGENT_SCOPE):
+                _, vjp_fn = jax.vjp(chunk_fn, bound, c_incs)
+                G_prev, g_incs = vjp_fn(G)
             return G_prev, g_incs
 
         _, g_rev = jax.lax.scan(outer, G, (boundaries, incs), reverse=True)
         g = jnp.moveaxis(g_rev.reshape(n_chunks * chunk, B, d), 0, 1)
         return (g[:, :M],)
 
-    proj.defvjp(fwd, bwd)
-    return proj
+    return scoped_vjp(proj, fwd, bwd)
 
 
 def projected_signature_from_increments(increments: jax.Array,

@@ -26,14 +26,38 @@ Three backward modes:
 from __future__ import annotations
 
 import math
-from functools import lru_cache, partial
+from functools import lru_cache, partial, wraps
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import DEVICE_SCOPES
+
 from . import tensor_ops as tops
 from .words import sig_dim
+
+(FWD_SCOPE, BWD_SCOPE, RECONSTRUCT_SCOPE, COTANGENT_SCOPE,
+ PROJ_INDEX_SCOPE) = DEVICE_SCOPES
+
+
+def _in_scope(name: str, fn):
+    @wraps(fn)
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return scoped
+
+
+def scoped_vjp(primal, fwd, bwd):
+    """``jax.custom_vjp(primal)`` with the rule ``(fwd, bwd)``: the primal
+    and ``fwd`` run under the device scope ``pathsig.fwd``, ``bwd`` under
+    ``pathsig.bwd``, so the compiled program names its own phases.  Every
+    signature custom VJP is made here; ``fwd`` calls the plain forward, not
+    the returned function, so the scope is entered once."""
+    f = jax.custom_vjp(_in_scope(FWD_SCOPE, primal))
+    f.defvjp(_in_scope(FWD_SCOPE, fwd), _in_scope(BWD_SCOPE, bwd))
+    return f
 
 
 def stream_emit_steps(M: int, stride: int = 1) -> np.ndarray:
@@ -256,7 +280,6 @@ def _make_fused_inverse_vjp(depth: int, spec):
     materialises the augmented increments (reusing :func:`inverse_bwd_scan`
     unchanged), then pulls the cotangent back through the transform's linear
     adjoint (:func:`repro.core.transforms.fused_adjoint`)."""
-    @jax.custom_vjp
     def sig(increments, taux):
         return _fused_scan_forward(increments, taux, spec, depth, False)
 
@@ -272,15 +295,13 @@ def _make_fused_inverse_vjp(depth: int, spec):
         g_incs = fused_adjoint(g_e, spec, increments.shape[-1])
         return g_incs, jnp.zeros_like(taux)
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 @lru_cache(maxsize=None)
 def _make_fused_stream_inverse_vjp(depth: int, spec, stride: int):
     """Streamed fused forward (emissions strided over the AUGMENTED step
     axis) + the generalised §4.2 reverse sweep."""
-    @jax.custom_vjp
     def sig(increments, taux):
         out = _fused_scan_forward(increments, taux, spec, depth, True)
         return _subsample_stream(out, out.shape[1], stride)
@@ -297,8 +318,7 @@ def _make_fused_stream_inverse_vjp(depth: int, spec, stride: int):
         g_incs = fused_adjoint(g_e, spec, increments.shape[-1])
         return g_incs, jnp.zeros_like(taux)
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -319,9 +339,11 @@ def inverse_bwd_scan(increments: jax.Array, out_flat: jax.Array,
 
     def step(carry, dx):
         S, G = carry  # S = S_{0,t_j}, G = ∂L/∂S_{0,t_j}
-        S_prev = tops.horner_step(S, -dx)          # Prop. 4.6
-        _, vjp_fn = jax.vjp(tops.horner_step, S_prev, dx)
-        G_prev, g_dx = vjp_fn(G)
+        with jax.named_scope(RECONSTRUCT_SCOPE):
+            S_prev = tops.horner_step(S, -dx)      # Prop. 4.6
+        with jax.named_scope(COTANGENT_SCOPE):
+            _, vjp_fn = jax.vjp(tops.horner_step, S_prev, dx)
+            G_prev, g_dx = vjp_fn(G)
         return (S_prev, G_prev), g_dx
 
     (_, _), g_rev = jax.lax.scan(
@@ -331,7 +353,6 @@ def inverse_bwd_scan(increments: jax.Array, out_flat: jax.Array,
 
 @lru_cache(maxsize=None)
 def _make_inverse_vjp(depth: int):
-    @jax.custom_vjp
     def sig(increments):
         return _scan_forward(increments, depth, stream=False)
 
@@ -343,8 +364,7 @@ def _make_inverse_vjp(depth: int):
         increments, out_flat = res
         return (inverse_bwd_scan(increments, out_flat, g_flat, depth),)
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -378,10 +398,13 @@ def stream_inverse_bwd_scan(increments: jax.Array, terminal_flat: jax.Array,
     def step(carry, xs):
         S, G = carry  # S = S_{0,t_j}, G = ∂L/∂S_{0,t_j} from steps > j
         dx, g_j = xs
-        G = [a + b for a, b in zip(G, tops.flat_to_levels(g_j, d, depth))]
-        S_prev = tops.horner_step(S, -dx)          # Prop. 4.6
-        _, vjp_fn = jax.vjp(tops.horner_step, S_prev, dx)
-        G_prev, g_dx = vjp_fn(G)
+        with jax.named_scope(RECONSTRUCT_SCOPE):
+            S_prev = tops.horner_step(S, -dx)      # Prop. 4.6
+        with jax.named_scope(COTANGENT_SCOPE):
+            G = [a + b for a, b in zip(G, tops.flat_to_levels(g_j, d,
+                                                               depth))]
+            _, vjp_fn = jax.vjp(tops.horner_step, S_prev, dx)
+            G_prev, g_dx = vjp_fn(G)
         return (S_prev, G_prev), g_dx
 
     (_, _), g_rev = jax.lax.scan(
@@ -399,7 +422,6 @@ def _subsample_stream(out: jax.Array, M: int, stride: int) -> jax.Array:
 
 @lru_cache(maxsize=None)
 def _make_stream_inverse_vjp(depth: int, stride: int):
-    @jax.custom_vjp
     def sig(increments):
         out = _scan_forward(increments, depth, stream=True)
         return _subsample_stream(out, increments.shape[1], stride)
@@ -413,8 +435,7 @@ def _make_stream_inverse_vjp(depth: int, stride: int):
         return (stream_inverse_bwd_scan(increments, terminal, g_steps, depth,
                                         stride),)
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -453,8 +474,9 @@ def checkpoint_bwd_scan(increments: jax.Array, boundaries, g_flat: jax.Array,
 
     def outer(G, xs):
         bound, c_incs = xs
-        _, vjp_fn = jax.vjp(chunk_fn, bound, c_incs)
-        G_prev, g_incs = vjp_fn(G)
+        with jax.named_scope(COTANGENT_SCOPE):  # the replay is the pull-back's
+            _, vjp_fn = jax.vjp(chunk_fn, bound, c_incs)
+            G_prev, g_incs = vjp_fn(G)
         return G_prev, g_incs
 
     _, g_rev = jax.lax.scan(outer, G, (boundaries, incs), reverse=True)
@@ -464,7 +486,6 @@ def checkpoint_bwd_scan(increments: jax.Array, boundaries, g_flat: jax.Array,
 
 @lru_cache(maxsize=None)
 def _make_checkpoint_vjp(depth: int, chunk: int):
-    @jax.custom_vjp
     def sig(increments):
         return _scan_forward(increments, depth, stream=False)
 
@@ -485,8 +506,7 @@ def _make_checkpoint_vjp(depth: int, chunk: int):
         return (checkpoint_bwd_scan(increments, boundaries, g_flat, depth,
                                     chunk),)
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 # ---------------------------------------------------------------------------

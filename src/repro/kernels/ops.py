@@ -172,7 +172,7 @@ from repro.core.signature import (as_lengths, canon_precision,
                                   checkpoint_bwd_scan,
                                   dataclasses_replace_nobp, default_chunk,
                                   inverse_bwd_scan, mask_increments,
-                                  quantise_increments,
+                                  quantise_increments, scoped_vjp,
                                   signature_from_increments,
                                   stream_emit_mask, stream_inverse_bwd_scan,
                                   unsupported_stream_backward)
@@ -467,7 +467,6 @@ def _pallas_sig_inverse(depth: int, batch_tile: int, split: int | None,
                          split=split, interpret=interpret,
                          precision=precision)
 
-    @jax.custom_vjp
     def sig(increments):
         return kernel(increments)
 
@@ -479,8 +478,7 @@ def _pallas_sig_inverse(depth: int, batch_tile: int, split: int | None,
         increments, out_flat = res
         return (inverse_bwd_scan(increments, out_flat, g_flat, depth),)
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 @plan_cache
@@ -499,7 +497,6 @@ def _pallas_sig_checkpoint(depth: int, chunk: int, batch_tile: int,
                          split=split, interpret=interpret,
                          precision=precision)
 
-    @jax.custom_vjp
     def sig(increments):
         out, _ = _forward(increments)
         return out
@@ -534,8 +531,7 @@ def _pallas_sig_checkpoint(depth: int, chunk: int, batch_tile: int,
         return (checkpoint_bwd_scan(increments, boundaries, g_flat, depth,
                                     chunk),)
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 @plan_cache
@@ -549,7 +545,6 @@ def _pallas_sig_stream(depth: int, stride: int, batch_tile: int,
                          split=split, interpret=interpret, stream=True,
                          stream_stride=stride, precision=precision)
 
-    @jax.custom_vjp
     def sig(increments):
         return kernel(increments)
 
@@ -562,8 +557,7 @@ def _pallas_sig_stream(depth: int, stride: int, batch_tile: int,
         return (stream_inverse_bwd_scan(increments, terminal, g_steps, depth,
                                         stride),)
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -589,7 +583,6 @@ def _pallas_sig_fused_inverse(depth: int, batch_tile: int, split: int | None,
                          split=split, interpret=interpret, transform=kspec,
                          taux=taux, precision=precision)
 
-    @jax.custom_vjp
     def sig(increments, taux):
         return kernel(increments, taux)
 
@@ -604,8 +597,7 @@ def _pallas_sig_fused_inverse(depth: int, batch_tile: int, split: int | None,
         return (fused_adjoint(g_e, kspec, increments.shape[-1]),
                 jnp.zeros_like(taux))
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 @plan_cache
@@ -621,7 +613,6 @@ def _pallas_sig_fused_stream(depth: int, stride: int, batch_tile: int,
                          stream_stride=stride, transform=kspec, taux=taux,
                          precision=precision)
 
-    @jax.custom_vjp
     def sig(increments, taux):
         return kernel(increments, taux)
 
@@ -636,8 +627,7 @@ def _pallas_sig_fused_stream(depth: int, stride: int, batch_tile: int,
         return (fused_adjoint(g_e, kspec, increments.shape[-1]),
                 jnp.zeros_like(taux))
 
-    sig.defvjp(fwd, bwd)
-    return sig
+    return scoped_vjp(sig, fwd, bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -701,7 +691,6 @@ def _pallas_proj_inverse(words: tuple, d: int, batch_tile: int, max_rows: int,
         ones = jnp.ones((cw.shape[0], 1), cw.dtype)
         return jnp.concatenate([ones, cw], axis=1)        # (B, 1 + W)
 
-    @jax.custom_vjp
     def proj(increments):
         return jnp.take(closure_state(increments), out_rows, axis=1)
 
@@ -713,8 +702,7 @@ def _pallas_proj_inverse(words: tuple, d: int, batch_tile: int, max_rows: int,
         increments, S_T = res
         return (projected_inverse_bwd_scan(increments, S_T, g_out, wplan),)
 
-    proj.defvjp(fwd, bwd)
-    return proj
+    return scoped_vjp(proj, fwd, bwd)
 
 
 @plan_cache
@@ -735,7 +723,6 @@ def _pallas_proj_stream(words: tuple, d: int, stride: int, batch_tile: int,
         ones = jnp.ones((*cw.shape[:2], 1), cw.dtype)
         return jnp.concatenate([ones, cw], axis=-1)  # (B, M_out, 1 + W)
 
-    @jax.custom_vjp
     def proj(increments):
         return jnp.take(closure_stream(increments), out_rows, axis=-1)
 
@@ -749,8 +736,7 @@ def _pallas_proj_stream(words: tuple, d: int, stride: int, batch_tile: int,
         return (projected_stream_inverse_bwd_scan(increments, S_T, g_steps,
                                                   wplan, stride),)
 
-    proj.defvjp(fwd, bwd)
-    return proj
+    return scoped_vjp(proj, fwd, bwd)
 
 
 @plan_cache
@@ -773,7 +759,6 @@ def _pallas_proj_fused_inverse(words: tuple, d: int, batch_tile: int,
         ones = jnp.ones((cw.shape[0], 1), cw.dtype)
         return jnp.concatenate([ones, cw], axis=1)
 
-    @jax.custom_vjp
     def proj(increments, taux):
         return jnp.take(closure_state(increments, taux), out_rows, axis=1)
 
@@ -788,8 +773,7 @@ def _pallas_proj_fused_inverse(words: tuple, d: int, batch_tile: int,
         return (fused_adjoint(g_e, kspec, increments.shape[-1]),
                 jnp.zeros_like(taux))
 
-    proj.defvjp(fwd, bwd)
-    return proj
+    return scoped_vjp(proj, fwd, bwd)
 
 
 @plan_cache
@@ -809,7 +793,6 @@ def _pallas_proj_fused_stream(words: tuple, d: int, stride: int,
         ones = jnp.ones((*cw.shape[:2], 1), cw.dtype)
         return jnp.concatenate([ones, cw], axis=-1)
 
-    @jax.custom_vjp
     def proj(increments, taux):
         return jnp.take(closure_stream(increments, taux), out_rows, axis=-1)
 
@@ -825,8 +808,7 @@ def _pallas_proj_fused_stream(words: tuple, d: int, stride: int,
         return (fused_adjoint(g_e, kspec, increments.shape[-1]),
                 jnp.zeros_like(taux))
 
-    proj.defvjp(fwd, bwd)
-    return proj
+    return scoped_vjp(proj, fwd, bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -1036,7 +1018,6 @@ def _gram_vjp(engine: str, interpret: bool, block_words: int, bx_tile: int,
                                   k_tile=block_words, interpret=interpret)
         return _gram_blocked_jax(Sx, Sy, w, block_words)
 
-    @jax.custom_vjp
     def gram_fn(Sx, Sy, w):
         return forward(Sx, Sy, w)
 
@@ -1053,8 +1034,7 @@ def _gram_vjp(engine: str, interpret: bool, block_words: int, bx_tile: int,
         dw = ((g.T @ Sx) * Sy).sum(axis=0).astype(w.dtype)
         return dSx, dSy, dw
 
-    gram_fn.defvjp(fwd, bwd)
-    return gram_fn
+    return scoped_vjp(gram_fn, fwd, bwd)
 
 
 @plan_cache

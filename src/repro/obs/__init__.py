@@ -7,7 +7,7 @@ Three modules, one import surface::
 
     obs.enable()                                  # or PATHSIG_METRICS=1
     obs.counter("my_events_total").inc()
-    with obs.span("my.phase", n=3):               # PATHSIG_TRACE=t.json
+    with obs.span("train.phase", n=3):            # PATHSIG_TRACE=t.json
         ...
     print(obs.to_prometheus())
 
@@ -15,8 +15,9 @@ Three modules, one import surface::
   sets; JSON snapshot, JSONL append, Prometheus text exporters; pull
   collectors.  Near-zero overhead when disabled (one flag check).
 - :mod:`repro.obs.trace` — span tracer exporting Chrome-trace/Perfetto
-  JSON; null-span fast path when inactive; optional ``jax.profiler``
-  bridge.
+  JSON; every span is also a ``jax.profiler`` host annotation, so it
+  shows in any profiler trace; the program's device scope names
+  (``DEVICE_SCOPES``).
 - :mod:`repro.obs.compile` — jit compile/retrace counters labelled with
   offending shape keys (:func:`instrument_jit`, :func:`count_trace`),
   lowered-cost and HLO-collective recording.
@@ -49,8 +50,8 @@ from .metrics import (DEFAULT_BUCKETS, DEFAULT_MAX_LABEL_SETS, REGISTRY,
 from .slo import (Slo, SloBreach, SloResult, batcher_slos, default_slos,
                   evaluate_log, evaluate_snapshot, evaluate_values,
                   session_slos, train_slos)
-from .trace import (TRACER, Tracer, instant, span, span_blocked, start_trace,
-                    stop_trace, trace_active, trace_scope)
+from .trace import (TRACER, Tracer, instant, span, start_trace, stop_trace,
+                    trace_active, trace_scope)
 
 __all__ = [
     # metrics
@@ -60,7 +61,7 @@ __all__ = [
     "snapshot", "to_prometheus", "write_snapshot", "append_jsonl",
     "register_collector", "jsonl_sink", "set_flight_sink",
     # trace
-    "Tracer", "TRACER", "span", "span_blocked", "instant", "start_trace",
+    "Tracer", "TRACER", "span", "instant", "start_trace",
     "stop_trace", "trace_active", "trace_scope",
     # compile accounting
     "TRACE_COUNTER_NAME", "shape_key", "count_trace", "instrument_jit",

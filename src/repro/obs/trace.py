@@ -16,19 +16,21 @@ Usage::
 
 Design rules (mirroring :mod:`repro.obs.metrics`):
 
-- **Disabled costs one flag check.** ``span()`` returns a shared null
-  context manager when no trace is active, so instrumented code paths pay
-  ~an attribute lookup when tracing is off.
+- **On the device trace's clock.** While a ``jax.profiler`` session runs
+  (``jax.profiler.trace``, the benchmark's), every span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name and args, so the session
+  shows the program's spans on its host plane beside the device ops.
+- **Idle costs one flag check.** With no trace file, no flight recorder
+  and no profiler session, ``span()`` returns a shared null context
+  manager.
 - **Nesting is implicit.** Spans emit Chrome "complete" (``ph: "X"``)
   events on one thread-id track; the viewer reconstructs the stack from
   containment.  A thread-local depth counter is recorded in ``args.depth``
   so tests (and offline tooling) can assert nesting without a viewer.
 - **jit-friendly.** Spans measure *host* wall-clock; device work launched
   asynchronously inside a span is attributed to it only up to dispatch.
-  Pass ``block=jax_array`` to :meth:`Span.done` — or use
-  :func:`span_blocked` — to include device completion.  With
-  ``PATHSIG_TRACE_JAX=1`` each span also enters ``jax.profiler.TraceAnnotation``
-  so the same names show up inside XLA's own profiler timeline.
+  Device time is read from a profiler trace, where the device ops carry
+  the program's named scopes (:data:`DEVICE_SCOPES`).
 
 - **Bounded buffer.** The in-memory event list is a ring of
   ``PATHSIG_TRACE_MAX_EVENTS`` (default 100000) most-recent events; on a
@@ -48,13 +50,25 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from . import metrics as _metrics
 
 __all__ = [
-    "Tracer", "TRACER", "span", "span_blocked", "instant",
+    "Tracer", "TRACER", "span", "instant",
     "start_trace", "stop_trace", "trace_active", "trace_scope",
-    "DEFAULT_MAX_EVENTS",
+    "DEFAULT_MAX_EVENTS", "DEVICE_SCOPES", "SPAN_PREFIXES",
 ]
+
+# The ``jax.named_scope`` names the program sets on its device work (the
+# op_name prefixes in compiled HLO): the custom VJPs' forward and backward,
+# the two halves of the §4.2 reverse scans' body, and the closure-table
+# gathers of the projected step.
+DEVICE_SCOPES = ("pathsig.fwd", "pathsig.bwd", "pathsig.bwd.reconstruct",
+                 "pathsig.bwd.cotangent", "pathsig.proj.index")
+
+# Every ``span()`` name in the program starts with one of these.
+SPAN_PREFIXES = ("kernels.", "serve.", "train.")
 
 _PID = os.getpid()
 
@@ -72,8 +86,12 @@ def _env_max_events() -> int:
     return max(1, n)
 
 
+# True while a jax.profiler session records host events.
+_profiling = TraceAnnotation.is_enabled
+
+
 class _NullSpan:
-    """Shared do-nothing context manager: the disabled fast path."""
+    """Shared do-nothing context manager: the idle fast path."""
 
     __slots__ = ()
 
@@ -90,8 +108,19 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _Annotation(TraceAnnotation):
+    """A span only a profiler session records: its host annotation alone,
+    with :class:`Span`'s ``set``."""
+
+    __slots__ = ()
+
+    def set(self, **args) -> "_Annotation":
+        self.set_metadata(**args)
+        return self
+
+
 class Span:
-    __slots__ = ("_tracer", "name", "args", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -99,29 +128,27 @@ class Span:
         self.args = args
         self._t0 = 0.0
         self._depth = 0
+        self._ann = TraceAnnotation(name, **args) if _profiling() else None
 
     def set(self, **args) -> "Span":
         """Attach/update args after entry (e.g. results known at exit)."""
         self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
         return self
 
     def __enter__(self) -> "Span":
-        tr = self._tracer
-        self._depth = tr._enter_depth()
-        if tr._jax_ann is not None:
-            ann = tr._jax_ann(self.name)
-            ann.__enter__()
-            tr._ann_stack_local().append(ann)
+        self._depth = self._tracer._enter_depth()
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tr = self._tracer
-        if tr._jax_ann is not None:
-            stack = tr._ann_stack_local()
-            if stack:
-                stack.pop().__exit__(*exc)
         tr._exit_depth()
         tr._emit(self.name, self._t0, t1, self._depth, self.args)
         return False
@@ -140,7 +167,6 @@ class Tracer:
         self._lock = threading.Lock()
         self._epoch = 0.0
         self._local = threading.local()
-        self._jax_ann = None       # jax.profiler.TraceAnnotation when bridged
         self._flight = None        # repro.obs.flight ring (always-on sink)
         self._record = False       # := _active or _flight is not None
         self.dropped = 0           # ring evictions since last reset
@@ -160,22 +186,13 @@ class Tracer:
         self._flight = recorder
         self._update_record()
 
-    def start(self, path: str | None = None, *, jax_bridge: bool = False,
-              reset: bool = True) -> None:
+    def start(self, path: str | None = None, *, reset: bool = True) -> None:
         with self._lock:
             if reset:
                 self._events.clear()
                 self.dropped = 0
             self._path = path
             self._epoch = time.perf_counter()
-            if jax_bridge:
-                try:
-                    import jax.profiler
-                    self._jax_ann = jax.profiler.TraceAnnotation
-                except Exception:
-                    self._jax_ann = None
-            else:
-                self._jax_ann = None
             self._active = True
             self._update_record()
 
@@ -230,12 +247,6 @@ class Tracer:
     def _exit_depth(self) -> None:
         self._local.depth = max(0, getattr(self._local, "depth", 1) - 1)
 
-    def _ann_stack_local(self) -> list:
-        st = getattr(self._local, "ann_stack", None)
-        if st is None:
-            st = self._local.ann_stack = []
-        return st
-
     def _append(self, ev: dict) -> None:
         dropped = False
         with self._lock:
@@ -284,9 +295,9 @@ class Tracer:
     # -- user API ----------------------------------------------------------
 
     def span(self, name: str, **args):
-        if not self._record:
-            return _NULL_SPAN
-        return Span(self, name, args)
+        if self._record:
+            return Span(self, name, args)
+        return _Annotation(name, **args) if _profiling() else _NULL_SPAN
 
     def instant(self, name: str, **args) -> None:
         if not self._record:
@@ -298,36 +309,21 @@ TRACER = Tracer()
 
 
 def span(name: str, **args):
-    """``with obs.span("kernels.signature", backend="pallas"):`` — null
-    context manager when neither a trace nor the flight recorder is
-    active."""
-    if not TRACER._record:
-        return _NULL_SPAN
-    return Span(TRACER, name, args)
-
-
-def span_blocked(name: str, fn, *fn_args, **span_args):
-    """Run ``fn(*fn_args)`` inside a span and ``block_until_ready`` the
-    result so device time lands in the span.  Returns fn's result."""
-    if not TRACER._record:
-        return fn(*fn_args)
-    with TRACER.span(name, **span_args):
-        out = fn(*fn_args)
-        try:
-            import jax
-            jax.block_until_ready(out)
-        except Exception:
-            pass
-    return out
+    """``with obs.span("kernels.signature", backend="pallas"):`` — the
+    profiler's annotation alone when neither a trace nor the flight
+    recorder is active, a null context manager when no profiler session
+    runs either."""
+    if TRACER._record:
+        return Span(TRACER, name, args)
+    return _Annotation(name, **args) if _profiling() else _NULL_SPAN
 
 
 def instant(name: str, **args) -> None:
     TRACER.instant(name, **args)
 
 
-def start_trace(path: str | None = None, *, jax_bridge: bool = False,
-                reset: bool = True) -> None:
-    TRACER.start(path, jax_bridge=jax_bridge, reset=reset)
+def start_trace(path: str | None = None, *, reset: bool = True) -> None:
+    TRACER.start(path, reset=reset)
 
 
 def stop_trace(path: str | None = None) -> str | None:
@@ -342,12 +338,11 @@ class trace_scope:
     """``with obs.trace_scope("t.json"):`` — start on entry, stop+write on
     exit.  Used by tests and ``benchmarks/run.py``."""
 
-    def __init__(self, path: str | None = None, *, jax_bridge: bool = False):
+    def __init__(self, path: str | None = None):
         self._path = path
-        self._jax = jax_bridge
 
     def __enter__(self) -> Tracer:
-        TRACER.start(self._path, jax_bridge=self._jax)
+        TRACER.start(self._path)
         return TRACER
 
     def __exit__(self, *exc):
@@ -357,8 +352,5 @@ class trace_scope:
 
 _ENV_TRACE = os.environ.get("PATHSIG_TRACE", "").strip()
 if _ENV_TRACE:
-    TRACER.start(
-        _ENV_TRACE,
-        jax_bridge=os.environ.get("PATHSIG_TRACE_JAX", "").strip()
-        in ("1", "on", "true"))
+    TRACER.start(_ENV_TRACE)
     atexit.register(TRACER.stop)

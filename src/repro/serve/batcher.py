@@ -226,7 +226,8 @@ class DynamicBatcher:
             nonlocal next_put
             while next_put < len(groups) and next_put < limit:
                 rung, B_pad, part, rp = groups[next_put]
-                placed.append((rung, B_pad, part, self._place(rp)))
+                with obs.span("serve.batcher.place", rung=rung, B_pad=B_pad):
+                    placed.append((rung, B_pad, part, self._place(rp)))
                 next_put += 1
 
         results: list = []
@@ -244,7 +245,8 @@ class DynamicBatcher:
             in_flight.append(res)
             self._in_flight_peak = max(self._in_flight_peak, len(in_flight))
             while len(in_flight) > max(1, window):
-                jax.block_until_ready(in_flight.popleft())
+                with obs.span("serve.batcher.retire"):
+                    jax.block_until_ready(in_flight.popleft())
         return results
 
     @obs.dump_on_error("batcher.flush")
@@ -257,9 +259,13 @@ class DynamicBatcher:
             return out
         t_flush = time.perf_counter()
         with obs.span("serve.batcher.flush", requests=len(queue)):
-            for part, res in self._run_groups(self._pack_groups(queue)):
-                for row, req in enumerate(part):
-                    out[req.ticket] = res[row]
+            with obs.span("serve.batcher.pack", requests=len(queue)):
+                groups = self._pack_groups(queue)
+            results = self._run_groups(groups)
+            with obs.span("serve.batcher.rows", rows=len(queue)):
+                for part, res in results:
+                    for row, req in enumerate(part):
+                        out[req.ticket] = res[row]
         self._flush_latencies.append(time.perf_counter() - t_flush)
         if obs.enabled():
             obs.histogram(

@@ -33,9 +33,12 @@ Contracts under test:
    dump behind.  Plus the bounded trace ring / metric-cardinality guard
    satellites.
 """
+import contextlib
 import dataclasses
+import glob
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -250,6 +253,113 @@ def test_null_span_supports_set():
     assert s.set(b=2) is s
     with s:
         pass
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under a ``jax.profiler`` session -> its ProfileData."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    return ProfileData.from_file(path)
+
+
+@contextlib.contextmanager
+def _nothing_records():
+    """Neither the trace file nor the flight ring records spans."""
+    was = obs.flight_active()
+    obs.disable_flight()
+    try:
+        assert not obs.trace_active() and not obs.TRACER._record
+        yield
+    finally:
+        if was:
+            obs.enable_flight()
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """With nothing in the process recording spans, a span is still a host
+    event of any profiler session, on the device ops' clock: its interval
+    holds the ops of the jitted call it waits for."""
+    def wrapped(x):
+        return jnp.sin(x) @ x
+
+    f = jax.jit(wrapped)
+    x = jnp.ones((128, 128))
+    f(x).block_until_ready()
+
+    def work():
+        with obs.span("serve.test.call", rows=3) as s:
+            f(x).block_until_ready()
+            assert s.set(done=1) is s
+
+    with _nothing_records():
+        pd = _profile(tmp_path, work)
+    spans, ops = [], []
+    for plane in pd.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                st = dict(ev.stats)
+                iv = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                if ev.name == "serve.test.call":
+                    spans.append((plane.name, iv, st))
+                elif st.get("hlo_module") == "jit_wrapped":
+                    ops.append(iv)
+    assert len(spans) == 1
+    plane, (t0, t1), st = spans[0]
+    assert plane.startswith("/host:")
+    assert st["rows"] == 3 and st["done"] == 1
+    assert ops and all(t0 <= a and b <= t1 for a, b in ops)
+
+
+def test_batcher_flush_idle_gaps_are_named_by_its_spans(tmp_path):
+    """The device's idle time inside a ``DynamicBatcher.flush()`` is named
+    by the batcher's own phase spans (``bench/program_trace.py``)."""
+    from bench import program_trace
+    from repro.serve import DynamicBatcher
+    db = DynamicBatcher.signature_service(2, 2, max_len=16, backend="jax",
+                                          min_bucket=4, max_batch=8)
+    rng = np.random.default_rng(0)
+
+    def submit():
+        for L in (3, 7, 5, 12, 2, 16):
+            db.submit(np.cumsum(rng.normal(size=(L + 1, 2)).astype(
+                np.float32), axis=0))
+    submit()
+    db.flush()                                  # compiles every rung
+    submit()
+
+    def work():
+        with jax.profiler.TraceAnnotation("bench.window"):
+            db.flush()
+
+    with _nothing_records():
+        red = program_trace.reduce(_profile(tmp_path, work))
+    gaps = dict(red["program_gaps"])
+    named = {n: v for n, v in gaps.items()
+             if n.startswith("serve.batcher.")}
+    assert {"serve.batcher.pack", "serve.batcher.rows"} <= set(named), gaps
+    assert sum(named.values()) >= 0.75 * sum(gaps.values()), gaps
+
+
+def test_span_prefixes_cover_the_program_spans():
+    """Every span the program opens is one that ``bench/program_trace.py``
+    names idle gaps by, and its copy of the prefixes is the same."""
+    from pathlib import Path
+
+    from bench import program_trace
+    from repro.obs.trace import SPAN_PREFIXES
+    assert program_trace.SPAN_PREFIXES == SPAN_PREFIXES
+    src = Path(obs.__file__).resolve().parents[1]
+    literal = re.compile(r"""\bspan\(\s*f?(["'])(.*?)\1""")
+    names = [m[1] for path in sorted(src.rglob("*.py"))
+             for m in literal.findall(path.read_text())]
+    assert len(names) >= 8, names
+    assert all(n.startswith(SPAN_PREFIXES) for n in names), names
 
 
 # ---------------------------------------------------------------------------
