@@ -38,8 +38,9 @@ jax, truncated         True    streamed scan fwd +           ✗                
 jax, projected         False   scan fwd + §4.2 reverse       √M boundaries + replay scan AD
 jax, projected         True    streamed scan fwd +           ✗                      scan AD
                                streamed §4.2 reverse
-pallas, truncated      False   kernel fwd + §4.2 reverse     kernel chunk fwd,      (jax)
-                                                             Chen-combined, √M bwd
+pallas, truncated      False   kernel fwd + kernel §4.2      kernel chunk fwd,      (jax)
+                               reverse (scan where the       Chen-combined, √M bwd
+                               blocks do not fit VMEM)
 pallas, truncated      True    streamed kernel fwd +         ✗                      (jax)
                                streamed §4.2 reverse
 pallas, projected      False   closure-kernel fwd +          (jax)                  (jax)
@@ -65,10 +66,14 @@ kernel-method loss trains in O(B·D_sig) signature memory end to end.
 The Pallas ``inverse`` rows are the paper's headline training path: the
 kernel computes the forward, the backward reconstructs
 S_{0,t_{j-1}} = S_{0,t_j} ⊗ exp(−ΔX_j) in O(B·D_sig) memory, independent of
-sequence length (§4.2).  The ``checkpoint`` row for truncated signatures runs
-the kernel over √M-length chunks folded into the batch axis, Chen-combines
-the chunk signatures (storing the √M boundary states), and replays chunks on
-the backward — drift-immune on very long paths.
+sequence length (§4.2).  For plain truncated signatures that backward is a
+second kernel (:mod:`repro.kernels.sig_trunc_bwd`), which holds S and its
+cotangent in VMEM for the whole reverse sweep; the XLA scan
+``inverse_bwd_scan`` runs instead where its blocks do not fit VMEM.  The
+``checkpoint`` row for truncated signatures runs the kernel over
+√M-length chunks folded into the batch axis, Chen-combines the chunk
+signatures (storing the √M boundary states), and replays chunks on the
+backward — drift-immune on very long paths.
 
 ``lengths`` column: EVERY cell above additionally accepts ``lengths=`` (B,)
 for ragged batches — orthogonal to backend × backward × stream because it is
@@ -168,7 +173,8 @@ from repro import obs
 from repro.core import tensor_ops as tops
 from repro.distributed.ctx import current_mesh, logical_axes
 from repro.distributed.ctx import shard as shard_constraint
-from repro.core.signature import (as_lengths, canon_precision,
+from repro.core.signature import (COTANGENT_SCOPE, as_lengths,
+                                  canon_precision,
                                   checkpoint_bwd_scan,
                                   dataclasses_replace_nobp, default_chunk,
                                   inverse_bwd_scan, mask_increments,
@@ -187,6 +193,7 @@ from repro.core.projection import (projected_inverse_bwd_scan,
 from repro.core.words import (TiledPlan, WordPlan, flat_index, make_plan,
                               make_tiled_plan, sig_dim)
 from .sig_gram import sig_gram_tiles
+from . import sig_trunc_bwd
 from .sig_trunc import sig_trunc
 from .sig_words import sig_words
 
@@ -476,7 +483,19 @@ def _pallas_sig_inverse(depth: int, batch_tile: int, split: int | None,
 
     def bwd(res, g_flat):
         increments, out_flat = res
-        return (inverse_bwd_scan(increments, out_flat, g_flat, depth),)
+        B, M, d = increments.shape
+        kernel = sig_trunc_bwd.fits(B, M, d, depth, batch_tile)
+        obs.counter("pathsig_inverse_bwd_engine_total",
+                    "traced truncated inverse backwards, by engine (kernel: "
+                    "the Pallas reverse scan; scan: the XLA fallback where "
+                    "its blocks do not fit VMEM)", ("engine",)).inc(
+            engine="kernel" if kernel else "scan")
+        if not kernel:
+            return (inverse_bwd_scan(increments, out_flat, g_flat, depth),)
+        with jax.named_scope(COTANGENT_SCOPE):  # reconstruct fused inside
+            return (sig_trunc_bwd.sig_trunc_inverse_bwd(
+                increments, out_flat, g_flat, depth, interpret=interpret,
+                batch_tile=batch_tile),)
 
     return scoped_vjp(sig, fwd, bwd)
 

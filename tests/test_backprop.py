@@ -6,7 +6,10 @@ import numpy as np
 import pytest
 
 import repro.core as C
+from repro import obs
+from repro.core.signature import inverse_bwd_scan
 from repro.core.words import make_plan
+from repro.kernels import ops, sig_trunc_bwd
 from tests.conftest import make_path
 
 
@@ -109,3 +112,43 @@ def test_inverse_reconstruction_drift_bounded(rng):
     denom = float(jnp.max(jnp.abs(g_cp))) + 1e-12
     rel = float(jnp.max(jnp.abs(g_inv - g_cp))) / denom
     assert rel < 5e-3, rel
+
+
+def _engine_ticks():
+    c = obs.counter("pathsig_inverse_bwd_engine_total",
+                    labelnames=("engine",))
+    return {e: c.value(engine=e) for e in ("kernel", "scan")}
+
+
+@pytest.mark.parametrize("d,N,B,M", [
+    *[(d, N, B, M) for d, N in [(2, 4), (3, 3), (4, 5)]
+      for B in (1, 130) for M in (1, 7, 64)],
+    (2, 4, 1, 13_000),   # its (M, d) blocks overflow VMEM: the scan runs
+])
+def test_kernel_inverse_backward_matches_scan(d, N, B, M):
+    """The Pallas §4.2 backward of the dispatch's truncated ``inverse``
+    cell against ``inverse_bwd_scan`` and scan autodiff, and the engine
+    the counter says ran."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(d * 1000 + B + M))
+    x = jax.random.normal(k1, (B, M, d)) / np.sqrt(M)
+    out = C.signature_from_increments(x, N, backend="jax")
+    g = jax.random.normal(k2, out.shape)
+    kernel = sig_trunc_bwd.fits(B, M, d, N, 128)
+    assert kernel == (M < 13_000)
+
+    with obs.enabled_scope():
+        before = _engine_ticks()
+        out_k, vjp = jax.vjp(lambda x: ops.signature(
+            x, N, backend="pallas_interpret", backward="inverse"), x)
+        g_k, = vjp(g)
+        after = _engine_ticks()
+    ran = "kernel" if kernel else "scan"
+    assert after == {**before, ran: before[ran] + 1}
+
+    g_scan = inverse_bwd_scan(x, out_k, g, N)
+    _, vjp_ad = jax.vjp(lambda x: C.signature_from_increments(
+        x, N, backend="jax", backward="autodiff"), x)
+    g_ad, = vjp_ad(g)
+    scale = float(jnp.max(jnp.abs(g_ad)))
+    np.testing.assert_allclose(g_k, g_scan, rtol=1e-4, atol=1e-5 * scale)
+    np.testing.assert_allclose(g_k, g_ad, rtol=1e-4, atol=1e-5 * scale)

@@ -41,7 +41,8 @@ CASES = {
     "checkpoint": (_trunc("checkpoint"), False, False),
     "projected_inverse": (_proj("inverse"), True, True),
     "projected_checkpoint": (_proj("checkpoint"), False, True),
-    "kernel_inverse": (_trunc("inverse", backend="pallas_interpret"), True,
+    # the reverse-scan kernel fuses the reconstruction into its pull-back
+    "kernel_inverse": (_trunc("inverse", backend="pallas_interpret"), False,
                        False),
     "hybrid_inverse": (_proj("inverse", backend="hybrid"), True, False),
     "fused_leadlag": (_proj("inverse", backend="pallas_interpret",

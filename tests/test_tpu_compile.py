@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.words import all_words, anisotropic_words
-from repro.kernels import ops
+from repro.kernels import ops, sig_trunc_bwd
 from repro.kernels.sig_gram import sig_gram_tiles
 from repro.kernels.sig_trunc import sig_trunc
 from repro.kernels.sig_words import sig_words
@@ -65,12 +65,19 @@ def test_sig_trunc_compiles(one_chip, B, M, d, depth, split):
     assert "tpu_custom_call" in txt
 
 
-def test_inverse_training_step_compiles(one_chip):
-    """§4.2: kernel forward, inverse-reconstruction backward."""
-    sig = ops._pallas_sig_inverse(5, 128, None, False)
+@pytest.mark.parametrize("B,M,d,depth,kernels", [
+    (1024, 500, 4, 5, 2),     # paper Table 1 widths: forward and backward
+    (1024, 100, 6, 5, None),  # whichever backward the VMEM check picks
+])
+def test_inverse_training_step_compiles(one_chip, B, M, d, depth, kernels):
+    """§4.2: kernel forward, and the reverse-scan kernel backward where its
+    blocks fit VMEM (else the XLA scan)."""
+    sig = ops._pallas_sig_inverse(depth, 128, None, False)
     step = jax.value_and_grad(lambda x: jnp.sum(sig(x) ** 2))
-    txt = _compiled_text(step, _f32(one_chip, 1024, 100, 6))
-    assert "tpu_custom_call" in txt
+    txt = _compiled_text(step, _f32(one_chip, B, M, d))
+    if kernels is None:
+        kernels = 2 if sig_trunc_bwd.fits(B, M, d, depth, 128) else 1
+    assert txt.count("tpu_custom_call") == kernels
 
 
 def test_streamed_sig_trunc_compiles(one_chip):
