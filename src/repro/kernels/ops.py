@@ -68,8 +68,10 @@ kernel computes the forward, the backward reconstructs
 S_{0,t_{j-1}} = S_{0,t_j} ⊗ exp(−ΔX_j) in O(B·D_sig) memory, independent of
 sequence length (§4.2).  For plain truncated signatures that backward is a
 second kernel (:mod:`repro.kernels.sig_trunc_bwd`), which holds S and its
-cotangent in VMEM for the whole reverse sweep; the XLA scan
-``inverse_bwd_scan`` runs instead where its blocks do not fit VMEM.  The
+cotangent in VMEM for the whole reverse sweep, with or without a fused
+transform; the XLA scan ``inverse_bwd_scan`` runs instead where S and its
+cotangent do not fit VMEM.  Both truncated kernels stream the time axis
+through VMEM in chunks, so path length alone never forces the scan.  The
 ``checkpoint`` row for truncated signatures runs the kernel over
 √M-length chunks folded into the batch axis, Chen-combines the chunk
 signatures (storing the √M boundary states), and replays chunks on the
@@ -483,21 +485,29 @@ def _pallas_sig_inverse(depth: int, batch_tile: int, split: int | None,
 
     def bwd(res, g_flat):
         increments, out_flat = res
-        B, M, d = increments.shape
-        kernel = sig_trunc_bwd.fits(B, M, d, depth, batch_tile)
-        obs.counter("pathsig_inverse_bwd_engine_total",
-                    "traced truncated inverse backwards, by engine (kernel: "
-                    "the Pallas reverse scan; scan: the XLA fallback where "
-                    "its blocks do not fit VMEM)", ("engine",)).inc(
-            engine="kernel" if kernel else "scan")
-        if not kernel:
-            return (inverse_bwd_scan(increments, out_flat, g_flat, depth),)
-        with jax.named_scope(COTANGENT_SCOPE):  # reconstruct fused inside
-            return (sig_trunc_bwd.sig_trunc_inverse_bwd(
-                increments, out_flat, g_flat, depth, interpret=interpret,
-                batch_tile=batch_tile),)
+        return (_trunc_inverse_bwd(increments, out_flat, g_flat, depth,
+                                   batch_tile, interpret),)
 
     return scoped_vjp(sig, fwd, bwd)
+
+
+def _trunc_inverse_bwd(increments, out_flat, g_flat, depth: int,
+                       batch_tile: int, interpret: bool) -> jax.Array:
+    """The truncated §4.2 backward of the Pallas row: the reverse-scan
+    kernel, or the XLA scan where S and G do not fit VMEM."""
+    B, M, d = increments.shape
+    kernel = sig_trunc_bwd.fits(B, M, d, depth, batch_tile)
+    obs.counter("pathsig_inverse_bwd_engine_total",
+                "traced truncated inverse backwards, by engine (kernel: "
+                "the Pallas reverse scan; scan: the XLA fallback where "
+                "its blocks do not fit VMEM)", ("engine",)).inc(
+        engine="kernel" if kernel else "scan")
+    if not kernel:
+        return inverse_bwd_scan(increments, out_flat, g_flat, depth)
+    with jax.named_scope(COTANGENT_SCOPE):  # reconstruct fused inside
+        return sig_trunc_bwd.sig_trunc_inverse_bwd(
+            increments, out_flat, g_flat, depth, interpret=interpret,
+            batch_tile=batch_tile)
 
 
 @plan_cache
@@ -612,7 +622,8 @@ def _pallas_sig_fused_inverse(depth: int, batch_tile: int, split: int | None,
     def bwd(res, g_flat):
         increments, taux, out_flat = res
         e = fused_augment(increments, taux, kspec)
-        g_e = inverse_bwd_scan(e, out_flat, g_flat, depth)
+        g_e = _trunc_inverse_bwd(e, out_flat, g_flat, depth, batch_tile,
+                                 interpret)
         return (fused_adjoint(g_e, kspec, increments.shape[-1]),
                 jnp.zeros_like(taux))
 

@@ -5,8 +5,10 @@ word basis W_{<=N} is partitioned into *prefix cones*: grid cell ``c`` owns
 the level-``s`` prefix word ``u = digits_d(c)`` together with every
 descendant ``u∘v`` up to depth N, plus (redundantly) u's ancestor path —
 a prefix-closed set, the tile-granularity analogue of the paper's
-thread-per-``P_w`` assignment.  Each cell scans the whole time axis with its
-coefficients resident in VMEM.
+thread-per-``P_w`` assignment.  Each cell scans the time axis with its
+coefficients resident in VMEM; the increments arrive in chunks of T steps
+along a trailing grid axis, which runs in order (:func:`time_chunk`), so a
+path of any length streams through VMEM while the state stays put.
 
 Layout: batch on the 128-wide lane axis, words on sublanes, so the per-word
 Horner rule (paper Alg. 1) vectorises across the batch and the level-raising
@@ -93,14 +95,36 @@ def block_bytes(shape, itemsize: int = 4) -> int:
             * itemsize)
 
 
-def check_vmem(kernel: str, need: int, what: str) -> None:
+def check_vmem(kernel: str, need: int, what: str, hint: str) -> None:
     """Raise when a kernel's VMEM blocks cannot fit the scoped limit."""
     if need > VMEM_LIMIT_BYTES:
         raise ValueError(
             f"{kernel}: {what} needs {need / 2**20:.1f} MiB of VMEM, over "
-            f"the {VMEM_LIMIT_BYTES / 2**20:.0f} MiB limit.  The kernel holds "
-            "the whole time axis in VMEM; cut the path into windows, or for "
-            "stream=True raise stream_stride")
+            f"the {VMEM_LIMIT_BYTES / 2**20:.0f} MiB limit.  {hint}")
+
+
+# VMEM the blocks chunked over time may take, double-buffered: an eighth of
+# the scoped limit, which leaves the rest to the state and its temporaries.
+TIME_BLOCK_BYTES = VMEM_LIMIT_BYTES // 8
+
+
+def time_chunk(M: int, step_bytes: int) -> tuple[int, int]:
+    """-> (T, n_chunks): the time axis cut into ``n_chunks`` chunks of T
+    steps, the last padded with zero steps.  ``step_bytes`` is the VMEM of
+    one step of every block chunked over time, double-buffered.  The whole
+    axis is one chunk where it fits :data:`TIME_BLOCK_BYTES`; otherwise the
+    fewest chunks that fit, each as short as they allow."""
+    fit = max(1, TIME_BLOCK_BYTES // step_bytes)
+    if M <= fit:
+        return M, 1
+    n = -(-M // fit)
+    return -(-M // n), n
+
+
+def count_time_chunks(kernel: str, n: int) -> None:
+    obs.counter("pathsig_kernel_time_chunks_total",
+                "time chunks of traced kernel calls: grid steps along the "
+                "time axis per batch tile", ("kernel",)).inc(n, kernel=kernel)
 
 
 def lane_tile(B: int, batch_tile: int) -> tuple[int, int]:
@@ -115,15 +139,15 @@ def lane_tile(B: int, batch_tile: int) -> tuple[int, int]:
     return tile, -(-B // tile) * tile
 
 
-def vmem_footprint(M: int, d_raw: int, d: int, depth: int, s: int,
+def vmem_footprint(T: int, d_raw: int, d: int, depth: int, s: int,
                    batch_tile: int, *, M_out: int = 0, in_itemsize: int = 4,
                    out_itemsize: int = 4, buffers: int = 2) -> int:
-    """VMEM bytes of one grid step at split ``s``: the (M, d_raw) increment
-    block and the output block (the (M_out, rows) emission block when
-    streamed), each ``buffers``-deep, plus the streamed state scratch and
-    the top cone level's chain temporary."""
+    """VMEM bytes of one grid step at split ``s``: the (T, d_raw) increment
+    block of one time chunk and the output block (the (M_out, rows)
+    emission block when streamed), each ``buffers``-deep, plus the
+    streamed state scratch and the top cone level's chain temporary."""
     rows = max(0, s - 1) + cone_rows(d, depth, s)
-    blocks = block_bytes((M, d_raw, batch_tile), in_itemsize)
+    blocks = block_bytes((T, d_raw, batch_tile), in_itemsize)
     if M_out:
         blocks += block_bytes((M_out, rows, batch_tile), out_itemsize)
         scratch = block_bytes((rows, batch_tile))
@@ -134,12 +158,15 @@ def vmem_footprint(M: int, d_raw: int, d: int, depth: int, s: int,
             + block_bytes((d ** (depth - s), batch_tile)))
 
 
-def _kernel(incs_ref, *refs, d: int, depth: int, s: int, M: int,
+def _kernel(incs_ref, *refs, d: int, depth: int, s: int, T: int, M: int,
             stream_stride: int = 0, fuse_ll: bool = False,
             fuse_time: bool = False):
-    """Cone update loop.  Non-streamed: ``out_ref`` IS the running state.
-    Streamed (``stream_stride >= 1``): the state lives in the trailing VMEM
-    scratch ref and strided snapshots are stored into ``out_ref``.
+    """Cone update loop over one time chunk of T raw steps (grid axis 2; M
+    raw steps in all).  Non-streamed: ``out_ref`` IS the running state, a
+    block that every chunk of a cell revisits, so it stays in VMEM from the
+    first chunk, which zeroes it, to the last.  Streamed (``stream_stride
+    >= 1``, always one chunk): the state lives in the trailing VMEM scratch
+    ref and strided snapshots are stored into ``out_ref``.
 
     Fused transforms (``fuse_ll`` / ``fuse_time``): the input block holds RAW
     increments (M, d_raw, B) and each augmented increment — channel layout
@@ -148,7 +175,8 @@ def _kernel(incs_ref, *refs, d: int, depth: int, s: int, M: int,
     (M_aug, d_aug, B) block never exists; ``d`` is the AUGMENTED channel
     count and streamed emission is strided over the augmented step axis.
     ``fuse_time`` reads a (2, B) aux ref ``[dt; n_valid_aug]`` (zero time
-    increments past each example's true augmented end)."""
+    increments past each example's true augmented end, and so on the zero
+    steps that pad the last chunk)."""
     refs = list(refs)
     taux_ref = refs.pop(0) if fuse_time else None
     out_ref = refs.pop(0)
@@ -166,10 +194,14 @@ def _kernel(incs_ref, *refs, d: int, depth: int, s: int, M: int,
         return slice(n_path + int(co[k]), n_path + int(co[k + 1]))
 
     c = pl.program_id(1)
+    t0 = pl.program_id(2) * T  # the chunk's first raw step
     # letters of the cell's prefix word u (traced scalars, most significant first)
     letters = [(c // d ** (s - 1 - k)) % d for k in range(s)]
 
-    state_ref[...] = jnp.zeros(state_ref.shape, state_ref.dtype)
+    @pl.when(t0 == 0)
+    def _init():
+        state_ref[...] = jnp.zeros(state_ref.shape, state_ref.dtype)
+
     # one-hot row masks of the prefix letters: Mosaic has no dynamic_slice
     # on values, so ΔX^{(u_k)} is a masked sublane sum over the d channels
     channel = jax.lax.broadcasted_iota(jnp.int32, (d, state_ref.shape[1]), 0)
@@ -218,7 +250,7 @@ def _kernel(incs_ref, *refs, d: int, depth: int, s: int, M: int,
     def body(j, _):
         g = incs_ref[pl.ds(j, 1), :, :][0].astype(state_ref.dtype)  # (d_raw, B)
         for p in range(sub):
-            ja = sub * j + p  # augmented step index
+            ja = sub * (t0 + j) + p  # augmented step index
             if fuse_ll or fuse_time:
                 parts = ([jnp.zeros_like(g), g] if p == 0 else
                          [g, jnp.zeros_like(g)]) if fuse_ll else [g]
@@ -243,7 +275,7 @@ def _kernel(incs_ref, *refs, d: int, depth: int, s: int, M: int,
                         state_ref[...].astype(out_ref.dtype)[None]
         return 0
 
-    jax.lax.fori_loop(0, M, body, 0)
+    jax.lax.fori_loop(0, T, body, 0)
 
 
 def _reassemble(out, d, depth, s, B):
@@ -326,6 +358,10 @@ def sig_trunc(increments: jax.Array, depth: int, *, interpret: bool,
     a time channel.  ``precision="bf16_fp32"`` stores the increments block
     in bf16 (halved VMEM/HBM traffic) with fp32 accumulators.
 
+    The increments stream through VMEM in time chunks (:func:`time_chunk`),
+    one when the whole axis fits; the streamed kernel keeps the whole axis
+    in one block, as its emission block spans it anyway.
+
     ``split=None`` takes the smallest split whose state fits ``vmem_budget``
     and, when streamed, whose emission block fits the VMEM limit; a shape
     whose blocks fit at no split raises ``ValueError``.  ``interpret=True``
@@ -350,10 +386,16 @@ def sig_trunc(increments: jax.Array, depth: int, *, interpret: bool,
     M_out = -(-M_aug // stream_stride) if stream else 0
     batch_tile, B_pad = lane_tile(B, batch_tile)
     in_itemsize = jnp.dtype(_storage_dtype(precision)).itemsize
+    if stream:  # its emission block spans the whole axis anyway
+        T, n_t = M, 1
+    else:
+        T, n_t = time_chunk(
+            M, 2 * block_bytes((1, d_raw, batch_tile), in_itemsize))
+    count_time_chunks("sig_trunc", n_t)
 
     def need(s):  # Pallas double-buffers blocks unless the grid is 1 step
-        steps = B_pad // batch_tile * d**s
-        return vmem_footprint(M, d_raw, d, depth, s, batch_tile, M_out=M_out,
+        steps = B_pad // batch_tile * d**s * n_t
+        return vmem_footprint(T, d_raw, d, depth, s, batch_tile, M_out=M_out,
                               in_itemsize=in_itemsize,
                               out_itemsize=in_itemsize if stream else 4,
                               buffers=1 if steps == 1 else 2)
@@ -368,24 +410,32 @@ def sig_trunc(increments: jax.Array, depth: int, *, interpret: bool,
         raise ValueError(f"split {s} outside [0, {depth})")
     check_vmem("sig_trunc", need(s),
                f"(M={M}, d={d_raw}, depth={depth}, split={s}, "
-               f"batch_tile={batch_tile}, M_out={M_out})")
+               f"batch_tile={batch_tile}, M_out={M_out})",
+               "With stream=True the kernel holds every emitted step in "
+               "VMEM: raise stream_stride, or cut the path into windows"
+               if stream else "The state fits at no split: lower the depth "
+               "or the batch_tile")
     n_cells = d**s
     n_path = max(0, s - 1)
     rows = n_path + cone_rows(d, depth, s)
 
+    # zero steps pad the last chunk: a zero increment is the identity
+    # update, and the fused time channel is masked past the true end
     x = jnp.moveaxis(increments, 0, -1)  # (M, d_raw, B)
-    x = jnp.pad(x, ((0, 0), (0, 0), (0, B_pad - B))).astype(
+    x = jnp.pad(x, ((0, n_t * T - M), (0, 0), (0, B_pad - B))).astype(
         _storage_dtype(precision))
-    kern = functools.partial(_kernel, d=d, depth=depth, s=s, M=M,
+    kern = functools.partial(_kernel, d=d, depth=depth, s=s, T=T, M=M,
                              fuse_ll=fuse_ll, fuse_time=fuse_time)
     inputs = [x]
-    in_specs = [pl.BlockSpec((M, d_raw, batch_tile),
-                             lambda bi, c: (0, 0, bi))]
+    in_specs = [pl.BlockSpec((T, d_raw, batch_tile),
+                             lambda bi, c, t: (t, 0, bi))]
     if fuse_time:
         ta = jnp.pad(jnp.asarray(taux, jnp.float32).T,
                      ((0, 0), (0, B_pad - B)))  # (2, B_pad)
         inputs.append(ta)
-        in_specs.append(pl.BlockSpec((2, batch_tile), lambda bi, c: (0, bi)))
+        in_specs.append(pl.BlockSpec((2, batch_tile),
+                                     lambda bi, c, t: (0, bi)))
+    grid = (B_pad // batch_tile, n_cells, n_t)
 
     # the cell axis is a squeezed block dim, so each block's trailing
     # (rows, batch_tile) dims span the whole row extent: Mosaic accepts any
@@ -393,10 +443,10 @@ def sig_trunc(increments: jax.Array, depth: int, *, interpret: bool,
     if not stream:
         out = pl.pallas_call(
             kern,
-            grid=(B_pad // batch_tile, n_cells),
+            grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((None, rows, batch_tile),
-                                   lambda bi, c: (c, 0, bi)),
+                                   lambda bi, c, t: (c, 0, bi)),
             out_shape=jax.ShapeDtypeStruct((n_cells, rows, B_pad),
                                            jnp.float32),
             compiler_params=compiler_params(),
@@ -406,10 +456,10 @@ def sig_trunc(increments: jax.Array, depth: int, *, interpret: bool,
 
     out = pl.pallas_call(
         functools.partial(kern, stream_stride=stream_stride),
-        grid=(B_pad // batch_tile, n_cells),
+        grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((M_out, None, rows, batch_tile),
-                               lambda bi, c: (0, c, 0, bi)),
+                               lambda bi, c, t: (0, c, 0, bi)),
         # bf16_fp32: the (M_out, ·, ·) emission buffer is stored at the
         # precision's storage dtype (halving its VMEM/HBM footprint) while
         # the running state scratch stays a full fp32 accumulator

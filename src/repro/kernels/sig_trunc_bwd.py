@@ -6,10 +6,12 @@ reconstructs ``S_{j-1} = S_j ⊗ exp(-ΔX_j)`` (Prop. 4.6), recomputes the
 Horner chains of ``S_j = S_{j-1} ⊗ exp(ΔX_j)`` on it, and pulls the
 cotangent ``G = ∂L/∂S_j`` back through them to ``∂L/∂S_{j-1}`` and
 ``∂L/∂ΔX_j``.  :func:`repro.core.signature.inverse_bwd_scan` does that as
-an XLA scan whose carry crosses HBM every step; here one grid program holds
-a batch tile's S and G in VMEM for the whole time axis, so only the
-increments, the terminal state, its cotangent and the increment cotangent
-cross HBM — O(B·D_sig) whatever M is.
+an XLA scan whose carry crosses HBM every step; here a batch tile's S and
+G stay in VMEM scratch for the whole time axis, which the grid's trailing
+axis walks backwards in chunks of T steps
+(:func:`repro.kernels.sig_trunc.time_chunk`).  Only the increments, the
+terminal state, its cotangent and the increment cotangent cross HBM —
+O(B·D_sig) whatever M is, and the VMEM it takes does not grow with M.
 
 Layout: batch on the 128-wide lane axis, words on sublanes, as the cone
 kernel (``sig_trunc``) at split 0.  Within a level, rows are in REVERSED
@@ -46,7 +48,7 @@ from repro import obs
 from repro.core import tensor_ops as tops
 
 from .sig_trunc import VMEM_LIMIT_BYTES, block_bytes, compiler_params, \
-    lane_tile
+    count_time_chunks, lane_tile, time_chunk
 
 
 def _sum(xs):
@@ -60,32 +62,34 @@ def level_offsets(d: int, depth: int) -> np.ndarray:
     return np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
 
 
-def vmem_need(M: int, d: int, depth: int, batch_tile: int,
+def vmem_need(T: int, d: int, depth: int, batch_tile: int,
               buffers: int = 2) -> int:
-    """VMEM bytes of one grid step: the (M, d) increment and cotangent
-    blocks and the terminal state and cotangent blocks, each
-    ``buffers``-deep, the resident S and G, and the top chain's
+    """VMEM bytes of one grid step: the (T, d) increment and cotangent
+    blocks of one time chunk and the terminal state and cotangent blocks,
+    each ``buffers``-deep, the resident S and G, and the top chain's
     temporaries (its outer product and the cotangent it pulls back)."""
     rows = int(level_offsets(d, depth)[-1])
-    blocks = 2 * block_bytes((M, d, batch_tile)) \
+    blocks = 2 * block_bytes((T, d, batch_tile)) \
         + 2 * block_bytes((rows, batch_tile))
     return (buffers * blocks + 2 * block_bytes((rows, batch_tile))
             + 2 * block_bytes((d ** depth, batch_tile)))
 
 
-def _buffers(B: int, batch_tile: int) -> tuple[int, int, int]:
-    """-> (tile, B_pad, buffers): Pallas double-buffers the blocks unless
-    the grid is one step."""
+def _grid(B: int, M: int, d: int, batch_tile: int):
+    """-> (tile, B_pad, T, n_chunks, buffers): the lane tile, the time
+    chunks, and the depth of Pallas' buffers, double unless the grid is one
+    step."""
     tile, B_pad = lane_tile(B, batch_tile)
-    return tile, B_pad, 1 if B_pad == tile else 2
+    T, n_t = time_chunk(M, 2 * 2 * block_bytes((1, d, tile)))
+    return tile, B_pad, T, n_t, 1 if B_pad == tile and n_t == 1 else 2
 
 
 def fits(B: int, M: int, d: int, depth: int, batch_tile: int) -> bool:
-    """Whether the kernel holds this backward: a time axis to sweep and
-    blocks within the scoped-VMEM limit."""
-    tile, _, buffers = _buffers(B, batch_tile)
+    """Whether the kernel holds this backward: a time axis to sweep, and
+    S, G and their blocks within the scoped-VMEM limit."""
+    tile, _, T, _, buffers = _grid(B, M, d, batch_tile)
     return M > 0 and depth >= 1 and \
-        vmem_need(M, d, depth, tile, buffers) <= VMEM_LIMIT_BYTES
+        vmem_need(T, d, depth, tile, buffers) <= VMEM_LIMIT_BYTES
 
 
 def _to_rows(flat: jax.Array, d: int, depth: int, B_pad: int) -> jax.Array:
@@ -103,7 +107,9 @@ def _to_rows(flat: jax.Array, d: int, depth: int, B_pad: int) -> jax.Array:
 
 
 def _kernel(incs_ref, s_ref, g_ref, out_ref, S, G, *, d: int, depth: int,
-            M: int):
+            T: int):
+    """Steps T-1..0 of one time chunk; chunks run last to first (grid axis
+    1), and the first of them loads the terminal state and cotangent."""
     offs = level_offsets(d, depth)
 
     def rows(ref, n, lo=0, size=None):  # level n's rows [lo, lo + size)
@@ -115,8 +121,11 @@ def _kernel(incs_ref, s_ref, g_ref, out_ref, S, G, *, d: int, depth: int,
         ref[start:start + val.shape[0], :] = \
             ref[start:start + val.shape[0], :] + val
 
-    S[...] = s_ref[...]
-    G[...] = g_ref[...]
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        S[...] = s_ref[...]
+        G[...] = g_ref[...]
+
     B = S.shape[1]
     channel = jax.lax.broadcasted_iota(jnp.int32, (d, B), 0)
 
@@ -127,7 +136,7 @@ def _kernel(incs_ref, s_ref, g_ref, out_ref, S, G, *, d: int, depth: int,
         return jnp.sum(x, axis=0, keepdims=True)
 
     def body(k, carry):
-        j = M - 1 - k
+        j = T - 1 - k
         dx = incs_ref[pl.ds(j, 1), :, :][0].astype(jnp.float32)  # (d, B)
         letter = [incs_ref[pl.ds(j, 1), i:i + 1, :][0].astype(jnp.float32)
                   for i in range(d)]  # ΔX^i as (1, B) rows
@@ -183,7 +192,7 @@ def _kernel(incs_ref, s_ref, g_ref, out_ref, S, G, *, d: int, depth: int,
         out_ref[pl.ds(j, 1), :, :] = gdx[None].astype(out_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, M, body, 0)
+    jax.lax.fori_loop(0, T, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "batch_tile",
@@ -209,22 +218,26 @@ def sig_trunc_inverse_bwd(increments: jax.Array, out_flat: jax.Array,
             f"batch_tile={batch_tile}) does not fit the "
             f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB VMEM limit; use "
             "repro.core.signature.inverse_bwd_scan")
-    tile, B_pad, _ = _buffers(B, batch_tile)
+    tile, B_pad, T, n_t, _ = _grid(B, M, d, batch_tile)
+    count_time_chunks("sig_trunc_bwd", n_t)
     n_rows = int(level_offsets(d, depth)[-1])
+    # zero steps pad the last chunk, and the sweep meets them first: a zero
+    # increment leaves S and G as they are, and its cotangent is cut off
     x = jnp.moveaxis(increments, 0, -1).astype(jnp.float32)  # (M, d, B)
-    x = jnp.pad(x, ((0, 0), (0, 0), (0, B_pad - B)))
-    state_spec = pl.BlockSpec((n_rows, tile), lambda b: (0, b))
-    steps_spec = pl.BlockSpec((M, d, tile), lambda b: (0, 0, b))
+    x = jnp.pad(x, ((0, n_t * T - M), (0, 0), (0, B_pad - B)))
+    state_spec = pl.BlockSpec((n_rows, tile), lambda b, t: (0, b))
+    steps_spec = pl.BlockSpec((T, d, tile),
+                              lambda b, t: (n_t - 1 - t, 0, b))
     out = pl.pallas_call(
-        functools.partial(_kernel, d=d, depth=depth, M=M),
-        grid=(B_pad // tile,),
+        functools.partial(_kernel, d=d, depth=depth, T=T),
+        grid=(B_pad // tile, n_t),
         in_specs=[steps_spec, state_spec, state_spec],
         out_specs=steps_spec,
-        out_shape=jax.ShapeDtypeStruct((M, d, B_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_t * T, d, B_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n_rows, tile), jnp.float32),
                         pltpu.VMEM((n_rows, tile), jnp.float32)],
         compiler_params=compiler_params(),
         interpret=interpret,
     )(x, _to_rows(out_flat, d, depth, B_pad),
       _to_rows(g_flat, d, depth, B_pad))
-    return jnp.moveaxis(out[:, :, :B], -1, 0).astype(increments.dtype)
+    return jnp.moveaxis(out[:M, :, :B], -1, 0).astype(increments.dtype)

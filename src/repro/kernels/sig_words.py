@@ -194,7 +194,9 @@ def sig_words(increments: jax.Array, tplan: TiledPlan, *, interpret: bool,
                buffers * (block_bytes((M, d_raw, batch_tile), in_itemsize)
                           + tables + out_block),
                f"(M={M}, d={d_raw}, depth={depth}, tile rows={W1}, "
-               f"batch_tile={batch_tile}, M_out={M_out})")
+               f"batch_tile={batch_tile}, M_out={M_out})",
+               "The kernel holds the whole time axis in VMEM; cut the path "
+               "into windows, or for stream=True raise stream_stride")
     x = jnp.moveaxis(increments, 0, -1)
     x = jnp.pad(x, ((0, 0), (0, 0), (0, B_pad - B))).astype(
         _storage_dtype(precision))

@@ -60,10 +60,14 @@ def test_lane_tile_is_a_multiple_of_128_or_the_whole_batch(B, tile, want):
 
 def test_sig_trunc_blocks_over_vmem_raise():
     """A path whose VMEM blocks fit at no split fails with a clear error at
-    trace time, before any compile."""
+    trace time, before any compile: here the streamed emission block of
+    every step.  The same path unstreamed runs in time chunks."""
     x = jax.ShapeDtypeStruct((128, 40_000, 6), jnp.float32)
     with pytest.raises(ValueError, match="MiB of VMEM"):
-        jax.eval_shape(lambda a: sig_trunc(a, 3, interpret=True), x)
+        jax.eval_shape(lambda a: sig_trunc(a, 3, interpret=True,
+                                           stream=True), x)
+    out = jax.eval_shape(lambda a: sig_trunc(a, 3, interpret=True), x)
+    assert out.shape == (128, 258)
     # streamed: the split rises until the emission block fits
     y = jax.ShapeDtypeStruct((32, 2048, 4), jnp.float32)
     out = jax.eval_shape(lambda a: sig_trunc(a, 4, interpret=True,

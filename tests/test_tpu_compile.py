@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.transforms import as_transform
 from repro.core.words import all_words, anisotropic_words
 from repro.kernels import ops, sig_trunc_bwd
 from repro.kernels.sig_gram import sig_gram_tiles
@@ -78,6 +79,19 @@ def test_inverse_training_step_compiles(one_chip, B, M, d, depth, kernels):
     if kernels is None:
         kernels = 2 if sig_trunc_bwd.fits(B, M, d, depth, 128) else 1
     assert txt.count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("B", [128, 256])
+def test_long_fused_training_step_compiles(one_chip, B):
+    """EigenWorms' shapes, 17,983 steps of 6 channels and a fused time
+    channel at depth 4: both kernels stream the time axis in chunks, and
+    the backward is the kernel's, not the scan's."""
+    sig = ops._pallas_sig_fused_inverse(4, 128, None, False,
+                                        as_transform("time_augment"), "fp32")
+    step = jax.value_and_grad(lambda x, t: jnp.sum(sig(x, t) ** 2))
+    txt = _compiled_text(step, _f32(one_chip, B, 17_983, 6),
+                         _f32(one_chip, B, 2))
+    assert txt.count("tpu_custom_call") == 2
 
 
 def test_streamed_sig_trunc_compiles(one_chip):
