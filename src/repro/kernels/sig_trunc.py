@@ -11,11 +11,18 @@ along a trailing grid axis, which runs in order (:func:`time_chunk`), so a
 path of any length streams through VMEM while the state stays put.
 
 Layout: batch on the 128-wide lane axis, words on sublanes, so the per-word
-Horner rule (paper Alg. 1) vectorises across the batch and the level-raising
-outer product is a sublane reshape-broadcast — no gathers anywhere.
+Horner rule (paper Alg. 1) vectorises across the batch.  The §4.2 backward
+kernel (``sig_trunc_bwd``) shares the layout (:func:`level_offsets`,
+:func:`reverse_words`): within a level the suffix ``v = i_1…i_k`` of the
+cell's prefix sits at row ``i_k d^{k-1} + … + i_1`` (reversed word order),
+and each level starts at a row that is a multiple of 8.  The level-raising
+outer product ``(t ⊗ ΔX)[v∘i] = t[v]·ΔX^i`` is then d blocks ``t·ΔX^i``
+stacked along sublanes — no gathers, and no reshape across the sublane
+tile.  The wrapper maps the rows back to word order once (:func:`from_rows`).
 
 Per-cell state block (rows × B_TILE), rows =
-  [ path: levels 1..s-1 along u ] ++ [ cone levels s..N: d^0, d^1, ..., d^{N-s} rows ]
+  [ cone levels s..N: d^0, d^1, ..., d^{N-s} rows, each padded to 8 ]
+  ++ [ path: levels 1..s-1 along u, one row each ]
 
 Streaming (``stream=True``): the running state lives in a VMEM scratch block
 and every ``stream_stride``-th step (plus the terminal step) is copied into an
@@ -44,11 +51,44 @@ def cone_base_level(s: int) -> int:
     return max(s, 1)
 
 
-def cone_offsets(d: int, depth: int, s: int) -> np.ndarray:
-    """Row offsets of cone global levels n = base..depth inside the block."""
-    base = cone_base_level(s)
-    sizes = [d ** (n - s) for n in range(base, depth + 1)]
+def level_offsets(d: int, depth: int, lowest: int = 1) -> np.ndarray:
+    """Row offset of each level k = lowest..depth, of d^k words, in a
+    kernel's state block, each rounded up to the 8-row sublane tile; the
+    levels' rows last."""
+    sizes = [-(-d ** k // 8) * 8 for k in range(lowest, depth + 1)]
     return np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
+
+
+def reverse_words(x, d: int, k: int, axis: int = -2):
+    """Reorder ``x``'s axis of d^k words between word order (``i_1…i_k`` at
+    ``i_1 d^{k-1} + … + i_k``) and the kernels' reversed order (at
+    ``i_k d^{k-1} + … + i_1``).  It reverses base-d digits, so it is its
+    own inverse."""
+    axis %= x.ndim
+    digits = x.reshape(*x.shape[:axis], *(d,) * k, *x.shape[axis + 1:])
+    perm = (*range(axis), *range(axis + k - 1, axis - 1, -1),
+            *range(axis + k, digits.ndim))
+    return digits.transpose(perm).reshape(x.shape)
+
+
+def from_rows(x: jax.Array, d: int, depth: int, lowest: int = 1):
+    """(..., cells, rows, lanes) in the kernels' layout, levels
+    lowest..depth -> (..., cells · Σ d^k, lanes): level after level, each
+    cell's d^k words in word order after the cell before's."""
+    offs = level_offsets(d, depth, lowest)
+    *lead, cells, _, lanes = x.shape
+    levels = []
+    for j, k in enumerate(range(lowest, depth + 1)):
+        blk = x[..., int(offs[j]):int(offs[j]) + d ** k, :]
+        levels.append(reverse_words(blk, d, k).reshape(
+            *lead, cells * d ** k, lanes))
+    return jnp.concatenate(levels, axis=-2)
+
+
+def cone_offsets(d: int, depth: int, s: int) -> np.ndarray:
+    """Row offsets of cone global levels n = base..depth inside the block,
+    each 8-row aligned; the cone's rows last."""
+    return level_offsets(d, depth - s, cone_base_level(s) - s)
 
 
 def cone_rows(d: int, depth: int, s: int) -> int:
@@ -183,15 +223,19 @@ def _kernel(incs_ref, *refs, d: int, depth: int, s: int, T: int, M: int,
     scratch = refs
     stream = bool(scratch)
     state_ref = scratch[0] if stream else out_ref
-    n_path = max(0, s - 1)
     base = cone_base_level(s)
     co = cone_offsets(d, depth, s)
+    n_cone = int(co[-1])  # the ancestor path's rows follow the cone
     sub = 2 if fuse_ll else 1
     M_aug = M * sub
+    d_raw = incs_ref.shape[1]
 
-    def cone_slice(n):  # rows of global level n (n >= base)
-        k = n - base
-        return slice(n_path + int(co[k]), n_path + int(co[k + 1]))
+    def cone_slice(n, lo=0, size=None):  # level n's rows [lo, lo + size)
+        start = int(co[n - base]) + lo
+        return slice(start, start + (d ** (n - s) if size is None else size))
+
+    def path_val(lev):  # old value of ancestor u_{1:lev}, lev in 1..s-1
+        return state_ref[n_cone + lev - 1:n_cone + lev, :]
 
     c = pl.program_id(1)
     t0 = pl.program_id(2) * T  # the chunk's first raw step
@@ -208,60 +252,71 @@ def _kernel(incs_ref, *refs, d: int, depth: int, s: int, T: int, M: int,
     letter_mask = [(channel == letters[k]).astype(state_ref.dtype)
                    for k in range(s)]
 
-    def update(dx):
-        """One augmented-increment Horner update.  dx: (d, B) in state dtype."""
-        B = dx.shape[-1]
+    def update(dx, rows):
+        """One augmented-increment Horner update.  dx: (d, B) in state
+        dtype, and ``rows`` its d components ΔX^i as (1, B) rows."""
         # per-path-step increment components ΔX^{(u_k)}  -> (1, B)
         dxl = [jnp.sum(dx * letter_mask[k], axis=0, keepdims=True)
                for k in range(s)]
 
-        def path_val(lev):  # old value of ancestor u_{1:lev}, lev in 1..s-1
-            return state_ref[lev - 1:lev, :]
-
         def chain(n):
-            """Horner accumulator for target level n (paper Alg. 1):
-            acc_j = (S[w_{1:j-1}] + acc_{j-1}) · ΔX^{(i_j)} / (n-j+1)."""
-            acc = None
+            """Horner chain for target level n (paper Alg. 1),
+            acc_j = (S[w_{1:j-1}] + acc_{j-1}) · ΔX^{(i_j)} / (n-j+1), as
+            the blocks of acc_n: a cone link's d blocks t·ΔX^i / (n-j+1)
+            stack along sublanes in reversed word order."""
+            blocks = None
             for jj in range(1, n + 1):
                 inv = 1.0 / (n - jj + 1)
                 if jj == 1:          # innermost: S[eps] = 1
-                    acc = (dxl[0] if s >= 1 else dx) * inv
-                elif jj <= s:        # on-path step, width 1
-                    acc = (path_val(jj - 1) + acc) * dxl[jj - 1] * inv
+                    blocks = [(dxl[0] if s >= 1 else dx) * inv]
+                    continue
+                acc = blocks[0] if len(blocks) == 1 else \
+                    jnp.concatenate(blocks, axis=0)
+                if jj <= s:          # on-path step, width 1
+                    blocks = [(path_val(jj - 1) + acc) * dxl[jj - 1] * inv]
                 else:                # cone expansion: width d^{jj-1-s} -> d^{jj-s}
-                    prev = state_ref[cone_slice(jj - 1), :]
-                    t = prev + acc
-                    w = t.shape[0]
-                    acc = (t[:, None, :] * dx[None, :, :]).reshape(w * d, B) * inv
-            return acc
+                    t = state_ref[cone_slice(jj - 1), :] + acc
+                    blocks = [t * x for x in rows]
+                    if jj < n:       # the last link's 1 / (n-j+1) is 1
+                        blocks = [b * inv for b in blocks]
+            return blocks
 
         # top-down over global target levels: reads touch strictly lower levels
         for n in range(depth, base - 1, -1):
-            acc = chain(n)
-            sl = cone_slice(n)
-            state_ref[sl, :] = state_ref[sl, :] + acc
+            lo = 0
+            for blk in chain(n):
+                sl = cone_slice(n, lo, blk.shape[0])
+                state_ref[sl, :] = state_ref[sl, :] + blk
+                lo += blk.shape[0]
         # ancestor path levels n = s-1 .. 1 (width-1 chains)
         for n in range(min(s - 1, depth), 0, -1):
             acc = dxl[0] * (1.0 / n)
             for jj in range(2, n + 1):
                 acc = (path_val(jj - 1) + acc) * dxl[jj - 1] * (1.0 / (n - jj + 1))
-            state_ref[n - 1:n, :] = state_ref[n - 1:n, :] + acc
+            r = n_cone + n - 1
+            state_ref[r:r + 1, :] = state_ref[r:r + 1, :] + acc
 
     def body(j, _):
         g = incs_ref[pl.ds(j, 1), :, :][0].astype(state_ref.dtype)  # (d_raw, B)
+        raw = [incs_ref[pl.ds(j, 1), i:i + 1, :][0].astype(state_ref.dtype)
+               for i in range(d_raw)]  # ΔX^i as (1, B) rows
         for p in range(sub):
             ja = sub * (t0 + j) + p  # augmented step index
             if fuse_ll or fuse_time:
+                zero = [jnp.zeros_like(x) for x in raw]
+                rows = (zero + raw if p == 0 else raw + zero) if fuse_ll \
+                    else list(raw)
                 parts = ([jnp.zeros_like(g), g] if p == 0 else
                          [g, jnp.zeros_like(g)]) if fuse_ll else [g]
                 if fuse_time:
                     trow = taux_ref[0:1, :] * (
                         ja < taux_ref[1:2, :]).astype(state_ref.dtype)
                     parts = [trow] + parts
+                    rows = [trow] + rows
                 e = jnp.concatenate(parts, axis=0)  # (d_aug, B) in VMEM
             else:
-                e = g
-            update(e)
+                e, rows = g, raw
+            update(e, rows)
             if stream:
                 # strided per-step emission over the augmented axis: slot q
                 # holds S_{0,t_{ja+1}}; the terminal step is always emitted
@@ -279,40 +334,17 @@ def _kernel(incs_ref, *refs, d: int, depth: int, s: int, T: int, M: int,
 
 
 def _reassemble(out, d, depth, s, B):
-    """(n_cells, n_path+cone, B_pad) -> flat (B, D_sig)."""
-    n_cells = d**s
-    n_path = max(0, s - 1)
-    base = cone_base_level(s)
-    co = cone_offsets(d, depth, s)
+    """(..., n_cells, rows, B_pad) in the kernel's layout -> (B, ..., D_sig)
+    in word order: the streamed kernel's leading emission axis rides along."""
+    n_cone = cone_rows(d, depth, s)
     levels = []
     for lev in range(1, s):  # ancestor levels, gathered from owning cells
         idx = np.arange(d**lev) * d ** (s - lev)
-        levels.append(out[idx, lev - 1, :])  # (d^lev, B_pad)
-    for n in range(base, depth + 1):  # cone global levels
-        k = n - base
-        blk = out[:, n_path + int(co[k]):n_path + int(co[k + 1]), :]
-        levels.append(blk.reshape(n_cells * d ** (n - s), -1))
-    flat = jnp.concatenate(levels, axis=0)  # (D_sig, B_pad)
-    return flat[:, :B].T
-
-
-def _reassemble_stream(out, d, depth, s, B):
-    """(M_out, n_cells, n_path+cone, B_pad) -> (B, M_out, D_sig)."""
-    T = out.shape[0]
-    n_cells = d**s
-    n_path = max(0, s - 1)
-    base = cone_base_level(s)
-    co = cone_offsets(d, depth, s)
-    levels = []
-    for lev in range(1, s):  # ancestor levels, gathered from owning cells
-        idx = np.arange(d**lev) * d ** (s - lev)
-        levels.append(out[:, idx, lev - 1, :])  # (T, d^lev, B_pad)
-    for n in range(base, depth + 1):  # cone global levels
-        k = n - base
-        blk = out[:, :, n_path + int(co[k]):n_path + int(co[k + 1]), :]
-        levels.append(blk.reshape(T, n_cells * d ** (n - s), -1))
-    flat = jnp.concatenate(levels, axis=1)  # (T, D_sig, B_pad)
-    return jnp.moveaxis(flat[:, :, :B], -1, 0)  # (B, T, D_sig)
+        levels.append(out[..., idx, n_cone + lev - 1, :])  # (..., d^lev, B_pad)
+    levels.append(from_rows(out[..., :n_cone, :], d, depth - s,
+                            cone_base_level(s) - s))
+    flat = jnp.concatenate(levels, axis=-2)  # (..., D_sig, B_pad)
+    return jnp.moveaxis(flat[..., :B], -1, 0)
 
 
 def _fuse_flags(transform):
@@ -469,4 +501,4 @@ def sig_trunc(increments: jax.Array, depth: int, *, interpret: bool,
         compiler_params=compiler_params(),
         interpret=interpret,
     )(*inputs)
-    return _reassemble_stream(out, d, depth, s, B).astype(increments.dtype)
+    return _reassemble(out, d, depth, s, B).astype(increments.dtype)

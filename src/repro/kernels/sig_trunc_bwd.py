@@ -13,10 +13,11 @@ axis walks backwards in chunks of T steps
 terminal state, its cotangent and the increment cotangent cross HBM —
 O(B·D_sig) whatever M is, and the VMEM it takes does not grow with M.
 
-Layout: batch on the 128-wide lane axis, words on sublanes, as the cone
-kernel (``sig_trunc``) at split 0.  Within a level, rows are in REVERSED
-word order (word ``i_1…i_n`` at row ``i_n d^{n-1} + … + i_1``), and each
-level starts at a row that is a multiple of 8.  The Horner outer product
+Layout: the cone kernel's (``sig_trunc``) at split 0: batch on the
+128-wide lane axis, words on sublanes, within a level in REVERSED word
+order (word ``i_1…i_n`` at row ``i_n d^{n-1} + … + i_1``), each level
+starting at a row that is a multiple of 8 (``sig_trunc.level_offsets``,
+``sig_trunc.reverse_words``).  The Horner outer product
 ``(t ⊗ ΔX)[u∘i] = t[u]·ΔX^i`` is then d blocks ``t·ΔX^i`` stacked along
 sublanes, and both of its cotangents are block sums: no reshape across the
 sublane tile anywhere.  The wrapper permutes the terminal state and its
@@ -40,7 +41,6 @@ import operator
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -48,18 +48,11 @@ from repro import obs
 from repro.core import tensor_ops as tops
 
 from .sig_trunc import VMEM_LIMIT_BYTES, block_bytes, compiler_params, \
-    count_time_chunks, lane_tile, time_chunk
+    count_time_chunks, lane_tile, level_offsets, reverse_words, time_chunk
 
 
 def _sum(xs):
     return functools.reduce(operator.add, xs)
-
-
-def level_offsets(d: int, depth: int) -> np.ndarray:
-    """Row offset of each level 1..depth in the kernel's state block (each
-    rounded up to the 8-row sublane tile), and the block's rows last."""
-    sizes = [-(-d ** n // 8) * 8 for n in range(1, depth + 1)]
-    return np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
 
 
 def vmem_need(T: int, d: int, depth: int, batch_tile: int,
@@ -98,8 +91,7 @@ def _to_rows(flat: jax.Array, d: int, depth: int, B_pad: int) -> jax.Array:
     offs = level_offsets(d, depth)
     parts = []
     for n, lv in enumerate(tops.flat_to_levels(flat, d, depth), 1):
-        rev = jnp.transpose(lv.reshape(B, *(d,) * n),
-                            (*range(n, 0, -1), 0)).reshape(d ** n, B)
+        rev = reverse_words(lv, d, n, axis=1).T
         parts.append(jnp.pad(rev.astype(jnp.float32),
                              ((0, int(offs[n] - offs[n - 1]) - d ** n),
                               (0, B_pad - B))))

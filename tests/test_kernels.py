@@ -5,10 +5,12 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core.signature import stream_emit_steps
 from repro.core.words import (all_words, anisotropic_words, lyndon_words,
-                              make_plan, make_tiled_plan)
-from repro.kernels import ops, ref
-from repro.kernels.sig_trunc import choose_split, cone_rows, sig_trunc
+                              make_plan, make_tiled_plan, sig_dim)
+from repro.kernels import ops, ref, sig_trunc_bwd
+from repro.kernels.sig_trunc import (choose_split, cone_rows, from_rows,
+                                     level_offsets, reverse_words, sig_trunc)
 
 
 def _incs(seed, B, M, d, dtype=np.float32):
@@ -23,6 +25,7 @@ def _incs(seed, B, M, d, dtype=np.float32):
 @pytest.mark.parametrize("B,M,d,N", [
     (1, 1, 2, 1), (1, 5, 2, 3), (3, 17, 2, 6), (2, 9, 3, 4),
     (5, 13, 4, 3), (2, 7, 6, 2), (9, 21, 3, 5), (2, 3, 10, 2),
+    (2, 6, 7, 3), (3, 8, 4, 5),
 ])
 def test_sig_trunc_shapes(B, M, d, N):
     x = _incs(B * M * d, B, M, d)
@@ -31,12 +34,46 @@ def test_sig_trunc_shapes(B, M, d, N):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("split", [0, 1, 2, 3])
-def test_sig_trunc_splits_agree(split):
-    x = _incs(0, 2, 11, 3)
-    want = ref.sig_trunc_ref(x, 4)
-    got = sig_trunc(x, 4, split=split, batch_tile=8, interpret=True)
+@pytest.mark.parametrize("split,d,N,stride", [
+    *(pytest.param(s, 3, 4, 0, id=str(s)) for s in range(4)),
+    # levels off the 8-row tile (d = 3, 7) and on it (d = 4), whole and
+    # streamed: the kernel's rows map back to word order at every split
+    (0, 7, 3, 0), (1, 7, 3, 0), (2, 7, 3, 0), (0, 4, 4, 0), (2, 4, 4, 0),
+    (0, 3, 4, 1), (2, 3, 4, 3), (0, 7, 3, 3), (1, 7, 2, 1), (0, 4, 3, 1),
+    (1, 4, 4, 3),
+])
+def test_sig_trunc_splits_agree(split, d, N, stride):
+    x = _incs(0, 2, 11, d)
+    if not stride:
+        want = ref.sig_trunc_ref(x, N)
+        got = sig_trunc(x, N, split=split, batch_tile=8, interpret=True)
+    else:
+        want = jnp.stack([ref.sig_trunc_ref(x[:, :t + 1], N)
+                          for t in stream_emit_steps(11, stride)], axis=1)
+        got = sig_trunc(x, N, split=split, batch_tile=8, interpret=True,
+                        stream=True, stream_stride=stride)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("d", [2, 3, 4, 7])
+@pytest.mark.parametrize("N", [1, 2, 3, 4, 5])
+def test_kernel_layout_round_trips(d, N):
+    """Both truncated kernels hold a level in reversed word order, 8-row
+    aligned: ``_to_rows`` (word order in, the backward's state block out)
+    and ``from_rows`` (the forward's rows back to word order) are inverse,
+    and ``reverse_words`` reverses each word's letters."""
+    B = 3
+    flat = _incs(d * 10 + N, 1, B, sig_dim(d, N))[0]
+    rows = sig_trunc_bwd._to_rows(flat, d, N, 8)
+    assert rows.shape == (level_offsets(d, N)[-1], 8)
+    np.testing.assert_array_equal(from_rows(rows[None], d, N)[:, :B].T, flat)
+    k = min(N, 3)
+    words = np.arange(d ** k)
+    rev = reverse_words(words[:, None], d, k)[:, 0]
+    digits = [words // d ** (k - 1 - j) % d for j in range(k)]
+    assert np.array_equal(rev, sum(dg * d ** j for j, dg in enumerate(digits)))
+    np.testing.assert_array_equal(reverse_words(rev[:, None], d, k)[:, 0],
+                                  words)
 
 
 def test_sig_trunc_batch_tile_padding():

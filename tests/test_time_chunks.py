@@ -86,6 +86,10 @@ def test_cell_shapes_chunk_as_planned():
     (130, 21, 2, 4, 0, 128),    # two lane tiles
     (2, 19, 3, 4, 1, 8),        # 3 cones, each sweeping every chunk
     (4, 8, 2, 3, 0, 8),         # the chunk is the whole axis
+    (2, 19, 7, 3, 0, 8),        # levels of 7, 49, 343 rows, each 8-aligned
+    (2, 19, 7, 3, 1, 8),        # 7 cones of 1, 7, 49 rows
+    (3, 21, 4, 4, 0, 8),        # levels on the 8-row tile
+    (2, 17, 4, 4, 2, 8),
 ])
 def test_chunked_forward_is_bit_identical(steps, B, M, d, N, split,
                                           batch_tile):
@@ -104,12 +108,16 @@ def test_chunked_forward_is_bit_identical(steps, B, M, d, N, split,
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("tname", ["time_augment", "lead_lag",
-                                   "time_augment+lead_lag"])
-def test_chunked_fused_forward(steps, tname):
+@pytest.mark.parametrize("tname,d", [
+    *(pytest.param(t, 2, id=t) for t in ("time_augment", "lead_lag",
+                                         "time_augment+lead_lag")),
+    ("time_augment", 6), ("time_augment+lead_lag", 3),   # 7 channels
+    ("time_augment", 3),                                 # 4 channels
+])
+def test_chunked_fused_forward(steps, tname, d):
     """The fused channels see each step's global index: the time channel
     runs on across chunks and stops at the true end, not the padded one."""
-    B, M, d, N = 5, 29, 2, 3
+    B, M, N = 5, 29, 3
     x = _incs(7, B, M, d)
     spec = as_transform(tname)
     taux = transform_time_aux(spec, B, M)
@@ -143,6 +151,23 @@ def test_chunked_backward_is_bit_identical(steps, B, M, d, N):
         after = _ticks()
     assert after["sig_trunc_bwd"] - before["sig_trunc_bwd"] == n_t
     np.testing.assert_array_equal(got, whole)
+    scan = inverse_bwd_scan(x, out, g, N)
+    scale = float(jnp.max(jnp.abs(scan)))
+    np.testing.assert_allclose(got, scan, rtol=1e-4, atol=1e-5 * scale)
+
+
+@pytest.mark.parametrize("B,M,d,N", [(3, 29, 3, 3), (2, 21, 4, 4),
+                                     (2, 19, 7, 3)])
+def test_backward_takes_the_forward_kernel_state(steps, B, M, d, N):
+    """The §4.2 kernel reads the terminal state the cone forward wrote, in
+    the layout both kernels share: its gradient is the XLA scan's."""
+    x = _incs(M * d, B, M, d)
+    steps(8)
+    out = st.sig_trunc(x, N, batch_tile=8, interpret=True)
+    np.testing.assert_allclose(out, ref.sig_trunc_ref(x, N), rtol=2e-4,
+                               atol=2e-5)
+    g = jax.random.normal(jax.random.PRNGKey(d), out.shape)
+    got = sig_trunc_bwd.sig_trunc_inverse_bwd(x, out, g, N, interpret=True)
     scan = inverse_bwd_scan(x, out, g, N)
     scale = float(jnp.max(jnp.abs(scan)))
     np.testing.assert_allclose(got, scan, rtol=1e-4, atol=1e-5 * scale)

@@ -57,7 +57,9 @@ def _f32(sharding, *shape):
 
 @pytest.mark.parametrize("B,M,d,depth,split", [
     (1024, 500, 4, 5, 0),     # paper Table 1 widths, one cone per tile
-    (1024, 100, 6, 5, 1),     # split >= 1: 6 cones of 1555 rows each
+    (1024, 100, 6, 5, 1),     # split >= 1: 6 cones of 1568 rows each
+    (128, 17_983, 7, 4, 0),   # EigenWorms' channel count, in time chunks
+    (128, 17_983, 7, 4, 1),   # its 7 cones, levels of 1, 7, 49, 343 rows
 ])
 def test_sig_trunc_compiles(one_chip, B, M, d, depth, split):
     txt = _compiled_text(
