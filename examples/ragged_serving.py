@@ -79,10 +79,9 @@ def main() -> None:
     nearest = sb.flush()
     print(f"scoring_service(nearest): "
           f"{[int(nearest[t]) for t in t2]} (reference indices)")
-    print("\nragged serving OK — see benchmarks/ragged_throughput.py for "
-          "bucketed vs pad-to-max vs per-request numbers, and "
-          "examples/sessions_serving.py for the STATEFUL serving path "
-          "(pooled multi-tenant sessions with checkpoint/restore)")
+    print("\nragged serving OK — see examples/sessions_serving.py for the "
+          "STATEFUL serving path (pooled multi-tenant sessions with "
+          "checkpoint/restore)")
 
 
 if __name__ == "__main__":

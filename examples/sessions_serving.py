@@ -95,9 +95,8 @@ def main() -> None:
                for s in store._ids)
     print(f"resumed both sides with the replayed round; still identical: "
           f"{same}")
-    print("\nsessions serving OK — see benchmarks/session_throughput.py "
-          "for pooled vs per-object numbers, and examples/ragged_serving.py "
-          "for the per-request (stateless) serving path")
+    print("\nsessions serving OK — see examples/ragged_serving.py for the "
+          "per-request (stateless) serving path")
 
 
 if __name__ == "__main__":

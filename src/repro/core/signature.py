@@ -21,7 +21,7 @@ Three backward modes:
   backward recomputes within chunks — immune to inverse-reconstruction drift
   on very long/large-increment paths.
 - ``"autodiff"``: plain scan autodiff, O(M·B·D_sig) memory (keras_sig-style
-  scaling; used as the memory-law baseline in benchmarks).
+  scaling).
 """
 from __future__ import annotations
 

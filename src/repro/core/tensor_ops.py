@@ -119,7 +119,7 @@ def tensor_inverse(s: list[jax.Array]) -> list[jax.Array]:
 
 
 # ---------------------------------------------------------------------------
-# naive reference signature engines (oracles + in-repo competitor baselines)
+# naive reference signature engines (oracles)
 # ---------------------------------------------------------------------------
 
 def path_increments(path: jax.Array) -> jax.Array:
@@ -143,23 +143,6 @@ def signature_exp_chen(increments: jax.Array, depth: int) -> jax.Array:
                        increments.dtype)
     final, _ = jax.lax.scan(step, init, jnp.moveaxis(increments, -2, 0))
     return levels_to_flat(final)
-
-
-@partial(jax.jit, static_argnames=("depth",))
-def signature_cumulative(increments: jax.Array, depth: int) -> jax.Array:
-    """keras_sig-style baseline: returns ALL prefix signatures S_{0,t_j}.
-
-    Shape (M, B, D_sig); memory O(B·M·D_sig) — the scaling the paper's Table 2
-    contrasts against.  Used by benchmarks/table2_memory.py.
-    """
-    def step(levels, dx):
-        new = chen_mul(levels, tensor_exp(dx, depth))
-        return new, levels_to_flat(new)
-
-    init = zero_levels(increments.shape[:-2], increments.shape[-1], depth,
-                       increments.dtype)
-    _, ys = jax.lax.scan(step, init, jnp.moveaxis(increments, -2, 0))
-    return ys
 
 
 def horner_step(levels: list[jax.Array], dx: jax.Array) -> list[jax.Array]:

@@ -36,12 +36,13 @@ from .words import WordPlan, flat_index, sig_dim
 
 ROUTES = ("auto", "fold", "chen")
 
-# cost-model constants, calibrated against the measured BENCH_fig3.json grid
-# (tests/test_windows.py::test_auto_route_within_15pct_of_best re-checks the
-# calibration against the committed measurements):
+# cost-model constants, fitted to a grid of CPU wall times of the jax engine
+# (tests/data/windows_route_grid_cpu.json; tests/test_windows.py::
+# test_auto_route_within_15pct_of_best_on_fig3_grid re-checks the fit against
+# it).  They are not measured on the chip:
 #   * a streamed chen-route step costs ~_CHEN_STEP_COST fold-route scan steps
 #     (the streamed pass emits + stores a prefix signature per stride, the
-#     fold pass only accumulates) — implied unit costs from the fig3 records
+#     fold pass only accumulates) — implied unit costs from the grid's records
 #     are 1.45/2.17/2.33/3.22, median ~2.4;
 #   * a window's inverse + Chen combine costs ~_CHEN_COMBINE_STEPS steps;
 #   * the chen route must still win by _CHEN_ADVANTAGE before we accept its
@@ -49,7 +50,7 @@ ROUTES = ("auto", "fold", "chen")
 #     cost, now that _CHEN_STEP_COST carries the physics;
 #   * the fold route pays a fixed dispatch cost the streamed pass does not
 #     (window gather + (B, K, L, d) -> (B*K, L, d) reshape + fold-into-batch
-#     launch), ~0.3 ms on the fig3 grid ≈ _FOLD_OVERHEAD_STEPS fold steps.
+#     launch), ~0.3 ms on that grid ≈ _FOLD_OVERHEAD_STEPS fold steps.
 #     Without it the sub-millisecond records (small M·K) are unfittable: the
 #     measured grid has chen winning at M=48 but losing at M=144, which no
 #     pure work-ratio rule can reproduce.
@@ -79,8 +80,8 @@ def select_route(route: str, windows_np: np.ndarray, M: int,
     """Host-side cost model: fold work = K · L_max padded scan steps plus a
     fixed _FOLD_OVERHEAD_STEPS dispatch charge, chen work = one length-M
     streamed pass + ~_CHEN_COMBINE_STEPS steps per window, with each chen
-    step costing _CHEN_STEP_COST fold steps
-    (calibrated against BENCH_fig3.json measurements; scaled by
+    step costing _CHEN_STEP_COST fold steps (fitted to CPU wall times,
+    ``tests/data/windows_route_grid_cpu.json``; scaled by
     ``chen_cost_scale`` when the streamed pass runs over a larger basis than
     the fold route, e.g. full truncation vs a small closure).
 

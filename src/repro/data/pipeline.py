@@ -102,8 +102,8 @@ def hurst_dataset(seed: int, n_paths: int, n_steps: int, d: int,
 
 
 # ---------------------------------------------------------------------------
-# ragged (variable-length) generators — trainer and benchmarks draw their
-# mixed-length workloads from the SAME deterministic, seekable pipeline
+# ragged (variable-length) generators — trainer and serving workloads draw
+# their mixed lengths from the SAME deterministic, seekable pipeline
 # ---------------------------------------------------------------------------
 
 def geometric_lengths(seed: int, n: int, max_steps: int, min_steps: int = 2,
@@ -113,7 +113,7 @@ def geometric_lengths(seed: int, n: int, max_steps: int, min_steps: int = 2,
 
     ``mean_frac`` sets the pre-clip mean to ``mean_frac · max_steps``; the
     resulting clipped distribution has max/median >= ~4 for the default —
-    the serving-traffic shape the ragged benchmarks assume.  Same (seed, n,
+    the serving-traffic shape ``bench/generators.py`` copies.  Same (seed, n,
     max_steps) -> same lengths, always.
     """
     if not 1 <= min_steps <= max_steps:
@@ -149,7 +149,7 @@ class RaggedPathStream:
     Each batch is ``{"paths": (B, max_steps+1, d) frozen-tail padded,
     "path_lengths": (B,) int32}`` — exactly the keys
     ``TrainLoopConfig(loss="sig_mmd")`` consumes as ragged reference
-    sample, and the workload generator the ragged serving benchmarks reuse.
+    sample, and a mixed-length serving workload.
     ``kind="walk"`` draws scaled Gaussian random walks; ``"fbm"`` draws
     per-example-Hurst fBM (slower: one Cholesky per distinct H).
     Restoring ``state()`` resumes the exact stream (the per-batch RNG is

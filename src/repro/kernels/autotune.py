@@ -363,7 +363,7 @@ def sweep_cell(kind: str, cell: dict, repeats: int = 3) -> dict:
 # ---------------------------------------------------------------------------
 
 _QUICK_GRID = [
-    # (kind, cell) — paper-grid cells the benchmarks exercise
+    # (kind, cell) — small cells of the paper's grid
     ("sig_trunc", dict(d=6, depth=2, M=100, B=32, precision="fp32")),
     ("sig_trunc", dict(d=3, depth=3, M=200, B=32, precision="fp32")),
     ("sig_trunc", dict(d=6, depth=2, M=100, B=32, precision="bf16_fp32")),

@@ -1,9 +1,9 @@
 """JAX's persistent compilation cache at a fixed path.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``, ``launch/train.py``,
-``launch/serve.py``) call :func:`use_compile_cache` before their first
-compile; importing the library never does.  The path is fixed: a directory
-named by a temp name, a pid or a timestamp would never be found again.
+Entry points (``chip_smoke.py``, ``launch/train.py``, ``launch/serve.py``)
+call :func:`use_compile_cache` before their first compile; importing the
+library never does.  The path is fixed: a directory named by a temp name, a
+pid or a timestamp would never be found again.
 """
 from __future__ import annotations
 

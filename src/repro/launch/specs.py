@@ -90,57 +90,6 @@ def opt_state_specs_for(opt, params_sds):
     return jax.eval_shape(opt.init, params_sds)
 
 
-def hbm_bytes_estimate(cfg: ModelConfig, shape_name: str, n_dev: int,
-                       kind: str | None = None) -> float:
-    """Fusion-aware per-device HBM traffic per step (napkin model).
-
-    XLA's `bytes accessed` counts every HLO op unfused and overestimates real
-    DRAM traffic by 1-2 orders of magnitude; this analytic estimate assumes
-    perfect elementwise fusion: parameters, saved activations (remat=dots),
-    logits, optimizer state, and KV/state caches each move once per use.
-    """
-    info = SHAPES[shape_name]
-    B, S = info["batch"], info["seq"]
-    kind = kind or info["kind"]
-    P = cfg.param_count()
-    p_bytes = 2.0 * P / n_dev                     # bf16 params per device
-    d, ff = cfg.d_model, (cfg.d_ff_expert if cfg.moe else cfg.d_ff)
-    hd = cfg.resolved_head_dim
-
-    if kind == "decode":
-        # params once + cache read/write once
-        if cfg.family == "rwkv":
-            cache = cfg.n_layers * B * (d // cfg.rwkv_head_dim) * \
-                cfg.rwkv_head_dim ** 2 * 4
-        elif cfg.family == "hybrid":
-            d_in = cfg.mamba_expand * d
-            cache = cfg.n_layers * B * (d_in // cfg.mamba_head_dim) * \
-                cfg.mamba_head_dim * cfg.ssm_state * 4
-            cache += cfg.n_shared_attn_blocks * B * S * 2 * \
-                cfg.n_kv_heads * hd * 2
-        elif cfg.mla:
-            cache = cfg.n_layers * B * S * (cfg.kv_lora_rank +
-                                            cfg.qk_rope_dim) * 2
-        else:
-            cache = cfg.n_layers * B * S * 2 * cfg.n_kv_heads * hd * 2
-        act_p = cfg.active_param_count() if cfg.moe else P
-        return 2.0 * act_p / n_dev + 2.0 * cache / n_dev
-
-    tokens_dev = B * S / n_dev
-    # saved dot outputs per token per layer (remat="dots" policy)
-    attn_save = cfg.n_heads * hd + 2 * cfg.n_kv_heads * hd + d
-    ff_mult = (cfg.top_k + cfg.n_shared_experts) if cfg.moe else 1
-    mlp_save = ff_mult * (3 * ff) + d
-    act = tokens_dev * cfg.n_layers * (attn_save + mlp_save) * 2  # bf16
-    logits = tokens_dev * cfg.vocab_size * 4
-    if kind == "prefill":
-        return p_bytes + act + logits
-    # train: params fwd+bwd+update, adafactor state ~1.5 passes, acts saved
-    # then re-read in bwd, logits fwd+bwd
-    opt_bytes = 4.0 * P / n_dev * 0.5             # factored second moment
-    return 3 * p_bytes + 2 * opt_bytes + 2.5 * act + 2 * logits
-
-
 def flops_estimate(cfg: ModelConfig, shape_name: str) -> float:
     """MODEL_FLOPS: 6·N·D (dense train), 6·N_active·D (MoE); 2·N·D forward
     for prefill; 2·N_active per token for decode."""

@@ -1,7 +1,7 @@
 """repro.obs — process-wide observability: metrics, tracing, and
 compile/retrace accounting.
 
-Three modules, one import surface::
+Five modules, one import surface::
 
     from repro import obs
 
@@ -21,9 +21,6 @@ Three modules, one import surface::
 - :mod:`repro.obs.compile` — jit compile/retrace counters labelled with
   offending shape keys (:func:`instrument_jit`, :func:`count_trace`),
   lowered-cost and HLO-collective recording.
-- :mod:`repro.obs.baseline` — flat benchmark record schema, committed
-  baseline store, and the median/MAD statistical regression gate behind
-  ``benchmarks/run.py --compare``.
 - :mod:`repro.obs.slo` — declarative SLOs over snapshots / value dicts /
   JSONL run logs; backs ``SessionStore.health()``,
   ``DynamicBatcher.health()`` and the train loop's ``slo_callback``.
@@ -33,9 +30,9 @@ Three modules, one import surface::
   :func:`flight.dump` (``PATHSIG_FLIGHT=off`` disables).
 
 This package imports nothing from the rest of ``repro`` — every layer
-(kernels, distributed, serve, train, benchmarks) imports *it*.
+(core, kernels, serve, train) imports *it*.
 """
-from . import baseline, slo
+from . import slo
 from .compile import (TRACE_COUNTER_NAME, count_trace, instrument_jit,
                       record_collectives, record_cost, set_retrace_sink,
                       shape_key)
@@ -67,7 +64,7 @@ __all__ = [
     "TRACE_COUNTER_NAME", "shape_key", "count_trace", "instrument_jit",
     "record_cost", "record_collectives", "set_retrace_sink",
     # decision layer (PR 9)
-    "baseline", "slo", "Slo", "SloResult", "SloBreach", "evaluate_values",
+    "slo", "Slo", "SloResult", "SloBreach", "evaluate_values",
     "evaluate_snapshot", "evaluate_log", "default_slos", "session_slos",
     "batcher_slos", "train_slos",
     # flight recorder

@@ -128,7 +128,7 @@ def record_cost(site: str, fn, *args, **kwargs) -> dict:
     ``pathsig_lowered_bytes{site=}``.  Returns the raw cost dict.
 
     Compiles (cached by jax's jit cache when fn is already jitted with the
-    same signature) — opt-in for benchmarks/examples, not the hot path.
+    same signature) — opt-in for examples and tests, not the hot path.
     """
     import jax
 

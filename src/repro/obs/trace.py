@@ -336,7 +336,7 @@ def trace_active() -> bool:
 
 class trace_scope:
     """``with obs.trace_scope("t.json"):`` — start on entry, stop+write on
-    exit.  Used by tests and ``benchmarks/run.py``."""
+    exit.  Used by tests."""
 
     def __init__(self, path: str | None = None):
         self._path = path

@@ -36,8 +36,8 @@ def pytest_configure(config):
         "markers", "tpu: needs compiled Pallas kernels (a real TPU device); "
         "skipped elsewhere")
     config.addinivalue_line(
-        "markers", "slow: multi-second test (subprocess gate CLI, tiny "
-        "train loops); run by default, deselect with -m 'not slow'")
+        "markers", "slow: multi-second test (tiny train loops); run by "
+        "default, deselect with -m 'not slow'")
 
 
 def pytest_collection_modifyitems(config, items):

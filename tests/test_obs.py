@@ -16,18 +16,12 @@ Contracts under test:
 4. *Instrumented stack*: plan-cache eviction ticks the new ``evictions``
    counter without changing results; autotune lookups record outcomes;
    dispatch entries count calls.
-5. *Regression gate* (``repro.obs.baseline``): flat-record extraction,
-   median/MAD aggregation, verdict direction for higher/lower-is-better
-   metrics, baseline round-trip + schema guard, and the
-   ``benchmarks/run.py`` CLI end-to-end via the env-steered fixture suite
-   (update → clean compare exit 0 → injected regression exit 2 → crash
-   exit 1).
-6. *SLOs* (``repro.obs.slo``): all three evaluation surfaces (value
+5. *SLOs* (``repro.obs.slo``): all three evaluation surfaces (value
    dicts, registry snapshots with group_by, JSONL logs with burn-rate),
    plus the wired health endpoints — ``SessionStore.health()`` flags a
    seeded staleness breach and ``train_loop`` warns/aborts on its
    trailing-window bundle.
-7. *Flight recorder* (``repro.obs.flight``): bounded ring, Chrome-trace
+6. *Flight recorder* (``repro.obs.flight``): bounded ring, Chrome-trace
    dump contents (spans + retrace keys + exception), single-dump marker
    across nested boundaries, and a crashing ``train_loop`` leaving a
    dump behind.  Plus the bounded trace ring / metric-cardinality guard
@@ -39,8 +33,6 @@ import glob
 import json
 import os
 import re
-import subprocess
-import sys
 import time
 
 import jax
@@ -50,7 +42,6 @@ import pytest
 
 from repro import obs
 from repro.kernels import ops
-from repro.obs import baseline
 
 
 @pytest.fixture(autouse=True)
@@ -492,162 +483,7 @@ def test_dispatch_disabled_is_bitwise_transparent():
 
 
 # ---------------------------------------------------------------------------
-# 5. baseline store + regression gate
-# ---------------------------------------------------------------------------
-
-def test_record_unit_floor_and_roundtrip():
-    r = baseline.Record("s", "k/ms", 12.0, "ms")
-    assert r.noise_floor == baseline.UNIT_NOISE_FLOORS["ms"]
-    assert baseline.Record("s", "k/n", 3, "count").noise_floor == 0.0
-    assert baseline.Record("s", "k/?", 1.0, "weird").noise_floor == 0.10
-    # explicit floor survives json round-trip
-    r2 = baseline.Record("s", "k", 5.0, "ms", True, 0.4)
-    back = baseline.Record.from_json("s", r2.to_json())
-    assert back == r2
-
-
-def test_extract_records_native_schema_wins():
-    doc = {"baseline_records": [
-        {"key": "a/ms", "value": 3.0, "unit": "ms"},
-        {"key": "a/thr", "value": 9.0, "unit": "req/s",
-         "higher_is_better": True}],
-        "records": [{"B": 1}]}      # would crash the per-shape extractor
-    recs = baseline.extract_records("fig3", doc)
-    assert [r.key for r in recs] == ["a/ms", "a/thr"]
-    assert recs[1].higher_is_better
-
-
-def test_extract_records_per_shape_sessions():
-    doc = {"points": [{
-        "n_sessions": 512,
-        "pooled": {"updates_per_s_warm": 1000.0, "p99_staleness_s": 0.01,
-                   "compiled_shapes": 3},
-        "pooled_vs_per_object_speedup_warm": 40.0,
-        "max_abs_err_pooled_vs_per_object": 1e-6}]}
-    recs = {r.key: r for r in baseline.extract_records("sessions", doc)}
-    assert recs["sessions/S512/pooled_updates_per_s_warm"].higher_is_better
-    assert recs["sessions/S512/pooled_compiled_shapes"].noise_floor == 0.0
-    assert recs["sessions/S512/pooled_p99_staleness_s"].value == 0.01
-    # non-finite / missing values never become records
-    doc["points"][0]["pooled"]["updates_per_s_warm"] = float("nan")
-    del doc["points"][0]["pooled"]["p99_staleness_s"]
-    keys = {r.key for r in baseline.extract_records("sessions", doc)}
-    assert "sessions/S512/pooled_updates_per_s_warm" not in keys
-    assert "sessions/S512/pooled_p99_staleness_s" not in keys
-
-
-def test_aggregate_median_and_mad_widened_floor():
-    runs = [[baseline.Record("s", "k/q", v, "q")] for v in
-            (10.0, 100.0, 11.0)]    # one wild outlier (unknown-unit floor)
-    (agg,) = baseline.aggregate(runs)
-    assert agg.value == 11.0                    # median, not mean
-    # MAD = 1.0 -> scaled rel floor 4.45/11 ~ 0.40 > unit floor 0.10
-    assert agg.noise_floor == pytest.approx(3.0 * 1.4826 * 1.0 / 11.0)
-    # quiet reruns keep the unit floor
-    (q,) = baseline.aggregate([[baseline.Record("s", "k/q", 10.0, "q")],
-                               [baseline.Record("s", "k/q", 10.1, "q")]])
-    assert q.noise_floor == 0.10
-
-
-def test_compare_verdict_directions():
-    # explicit 25% floors so the assertions test direction logic, not the
-    # machine-calibrated unit defaults
-    base = {"s": [baseline.Record("s", "lat_ms", 10.0, "ms", False, 0.25),
-                  baseline.Record("s", "thr", 100.0, "req/s", True, 0.25),
-                  baseline.Record("s", "shapes", 4.0, "count"),
-                  baseline.Record("s", "gone", 1.0, "ms", False, 0.25)]}
-    cur = {"s": [baseline.Record("s", "lat_ms", 20.0, "ms", False, 0.25),
-                 baseline.Record("s", "thr", 30.0, "req/s", True, 0.25),
-                 baseline.Record("s", "shapes", 5.0, "count"),   # exact unit
-                 baseline.Record("s", "fresh", 7.0, "ms", False, 0.25)]}
-    v = {x.key: x for x in baseline.compare(cur, base)}
-    assert v["lat_ms"].status == "regressed" and v["lat_ms"].rel_delta < 0
-    assert v["thr"].status == "regressed"       # higher_is_better direction
-    assert v["shapes"].status == "regressed"    # count floor is exact
-    assert v["fresh"].status == "new"
-    assert v["gone"].status == "missing"
-    # improvements and in-floor jitter
-    cur2 = {"s": [baseline.Record("s", "lat_ms", 5.0, "ms", False, 0.25),
-                  baseline.Record("s", "thr", 101.0, "req/s", True, 0.25)]}
-    v2 = {x.key: x for x in baseline.compare(cur2, base)}
-    assert v2["lat_ms"].status == "improved"
-    assert v2["thr"].status == "ok"             # +1% is inside the floor
-    assert not baseline.regressions(baseline.compare(
-        {"s": base["s"]}, base))                # self-compare is all ok
-
-
-def test_verdict_table_orders_regressions_first():
-    base = {"s": [baseline.Record("s", "a_ms", 10.0, "ms"),
-                  baseline.Record("s", "b_ms", 10.0, "ms")]}
-    cur = {"s": [baseline.Record("s", "a_ms", 10.0, "ms"),
-                 baseline.Record("s", "b_ms", 99.0, "ms")]}
-    txt = baseline.verdict_table(baseline.compare(cur, base))
-    body = txt.splitlines()[2]                  # first data row
-    assert body.startswith("regressed") and "b_ms" in body
-    assert "2 metrics" in txt.splitlines()[-1]
-    hidden = baseline.verdict_table(baseline.compare(cur, base),
-                                    hide_ok=True)
-    assert "a_ms" not in hidden and "b_ms" in hidden
-
-
-def test_baseline_dir_roundtrip_and_schema_guard(tmp_path):
-    recs = [baseline.Record("mysuite", "k/ms", 3.25, "ms", False, 0.3)]
-    p = baseline.write_baseline(str(tmp_path), "mysuite", recs, reruns=3)
-    assert json.load(open(p))["reruns"] == 3
-    loaded = baseline.load_baseline_dir(str(tmp_path))
-    assert loaded["mysuite"] == recs
-    # future schema refuses to load silently-wrong
-    doc = json.load(open(p))
-    doc["schema"] = 99
-    json.dump(doc, open(p, "w"))
-    with pytest.raises(ValueError, match="schema"):
-        baseline.load_baseline(p)
-    assert baseline.load_baseline_dir(str(tmp_path / "nope")) == {}
-
-
-def _run_gate(tmp_path, extra_args, env_overrides):
-    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
-        [os.path.join(_REPO, "src"), _REPO]), **env_overrides)
-    env.pop("PATHSIG_FIXTURE_RAISE", None)
-    env.update(env_overrides)
-    return subprocess.run(
-        [sys.executable, "-m", "benchmarks.run", "--only", "fixture",
-         "--baseline-dir", str(tmp_path / "baselines")] + extra_args,
-        capture_output=True, text=True, env=env, cwd=str(tmp_path),
-        timeout=300)
-
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-@pytest.mark.slow
-def test_regression_gate_cli_end_to_end(tmp_path):
-    """update baselines -> clean compare exits 0 -> injected regression
-    exits 2 (EXIT_REGRESSED) -> crash exits 1 (EXIT_CRASH)."""
-    r = _run_gate(tmp_path, ["--update-baselines", "--reruns", "2"], {})
-    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
-    assert (tmp_path / "baselines" / "fixture.json").exists()
-
-    r = _run_gate(tmp_path, ["--compare"], {})
-    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
-    assert "regression gate" in r.stdout
-
-    out = tmp_path / "verdicts.json"
-    r = _run_gate(tmp_path, ["--compare", "--verdicts-out", str(out)],
-                  {"PATHSIG_FIXTURE_MS": "20.0"})       # 2x latency
-    assert r.returncode == 2, (r.returncode, r.stdout[-2000:])
-    assert "regressed" in r.stdout
-    doc = json.load(open(out))
-    assert any(v["status"] == "regressed" and v["key"] == "fixture/latency_ms"
-               for v in doc["verdicts"])
-
-    r = _run_gate(tmp_path, ["--compare"], {"PATHSIG_FIXTURE_RAISE": "1"})
-    assert r.returncode == 1, (r.returncode, r.stdout[-2000:])
-    assert "CRASHED" in r.stdout and "fixture FAIL" in r.stdout
-
-
-# ---------------------------------------------------------------------------
-# 6. SLOs
+# 5. SLOs
 # ---------------------------------------------------------------------------
 
 def test_slo_evaluate_values_surfaces():
@@ -802,7 +638,7 @@ def test_train_loop_slo_warn_and_abort(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# 7. flight recorder + bounded-ring satellites
+# 6. flight recorder + bounded-ring satellites
 # ---------------------------------------------------------------------------
 
 def test_flight_ring_is_bounded():
