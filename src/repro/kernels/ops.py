@@ -43,8 +43,11 @@ pallas, truncated      False   kernel fwd + kernel §4.2      kernel chunk fwd, 
                                blocks do not fit VMEM)
 pallas, truncated      True    streamed kernel fwd +         ✗                      (jax)
                                streamed §4.2 reverse
-pallas, projected      False   closure-kernel fwd +          (jax)                  (jax)
-                               §4.2 reverse
+pallas, projected      False   dense route: kernel fwd +     (jax)                  (jax)
+                               kernel §4.2 reverse to the
+                               set's depth, then a gather;
+                               else closure-kernel fwd +
+                               §4.2 reverse scan
 pallas, projected      True    streamed closure-kernel fwd   ✗                      (jax)
                                + streamed §4.2 reverse
 hybrid, projected      False   dense+top fwd + §4.2 reverse  (jax)                  top-level
@@ -76,6 +79,18 @@ through VMEM in chunks, so path length alone never forces the scan.  The
 √M-length chunks folded into the batch axis, Chen-combines the chunk
 signatures (storing the √M boundary states), and replays chunks on the
 backward — drift-immune on very long paths.
+
+The projected ``pallas`` × ``inverse`` row (not streamed, plain or with a
+fused transform) picks one of two engines per call, by shape alone.  The
+dense route runs the two truncated kernels to the word set's depth and
+gathers the words' coordinates from the ``(B, D_sig)`` output; it is taken
+where both kernels hold the shape (the forward's split fits VMEM and the
+backward runs the kernel, not the scan) and the truncated tensor has at
+most :data:`DENSE_ROWS_PER_CLOSURE_ROW` 8-row-aligned rows per row of the
+set's prefix closure.  Every other set runs the word kernel over the
+closure and the projected §4.2 reverse scan.  The counter
+``pathsig_projected_engine_total{engine="dense"|"words"}`` ticks once per
+traced call by the engine picked.
 
 ``lengths`` column: EVERY cell above additionally accepts ``lengths=`` (B,)
 for ragged batches — orthogonal to backend × backward × stream because it is
@@ -175,7 +190,8 @@ from repro import obs
 from repro.core import tensor_ops as tops
 from repro.distributed.ctx import current_mesh, logical_axes
 from repro.distributed.ctx import shard as shard_constraint
-from repro.core.signature import (COTANGENT_SCOPE, as_lengths,
+from repro.core.signature import (COTANGENT_SCOPE, PROJ_INDEX_SCOPE,
+                                  as_lengths,
                                   canon_precision,
                                   checkpoint_bwd_scan,
                                   dataclasses_replace_nobp, default_chunk,
@@ -196,7 +212,9 @@ from repro.core.words import (TiledPlan, WordPlan, flat_index, make_plan,
                               make_tiled_plan, sig_dim)
 from .sig_gram import sig_gram_tiles
 from . import sig_trunc_bwd
-from .sig_trunc import sig_trunc
+from .sig_trunc import (VMEM_LIMIT_BYTES, block_bytes, choose_split,
+                        lane_tile, level_offsets, sig_trunc, time_chunk,
+                        vmem_footprint)
 from .sig_words import sig_words
 
 BACKENDS = ("jax", "pallas", "pallas_interpret", "auto", "hybrid")
@@ -842,6 +860,106 @@ def _pallas_proj_fused_stream(words: tuple, d: int, stride: int,
 
 
 # ---------------------------------------------------------------------------
+# dense route: a projected set's coordinates are coordinates of the truncated
+# signature to the set's depth, so where that tensor is small next to the
+# set's prefix closure the truncated kernels (cone forward, §4.2 reverse-scan
+# kernel) compute it and the words are gathered from it.  A closure row's
+# Horner chain reads only its own prefixes, and the rows outside the closure
+# carry a zero cotangent, so both engines compute the same values and
+# gradients up to float32 rounding.
+# ---------------------------------------------------------------------------
+
+# The rule's row ratio: the dense route takes a set whose truncated tensor,
+# in the kernels' 8-row-aligned rows, has at most this many rows per row of
+# the set's prefix closure.  On a TPU v5e at the §8 shape a training step's
+# projected signature costs 105× as much per closure row on the word engine
+# as per row on the truncated kernels (PERF.md §6); half of that is 52, and
+# 32 leaves room for shapes whose per-row costs differ.
+DENSE_ROWS_PER_CLOSURE_ROW = 32
+
+
+def _dense_tiling(wplan: WordPlan, *, engine: str, interpret: bool,
+                  backward: str, stream: bool, batch_tile: int | None,
+                  B: int, M: int, precision: str):
+    """-> the truncated kernels' ``(batch_tile, split)`` where the dense
+    route may take a projected call, else None.  It may for a non-streamed
+    ``inverse`` call of the Pallas row whose truncated tensor has at most
+    :data:`DENSE_ROWS_PER_CLOSURE_ROW` rows per closure row.  The tiling is
+    what :func:`signature` takes for the same shape (``M`` counts augmented
+    steps); an explicit ``batch_tile`` wins."""
+    if engine != "pallas" or stream or backward != "inverse":
+        return None
+    rows = int(level_offsets(wplan.d, wplan.depth)[-1])
+    if rows > DENSE_ROWS_PER_CLOSURE_ROW * wplan.closure_size:
+        return None
+    if batch_tile is not None:
+        return batch_tile, None
+    hit = autotune.lookup("sig_trunc", engine=_tuned(engine, interpret),
+                          d=wplan.d, depth=wplan.depth, M=M, B=B,
+                          precision=precision)
+    return hit.get("batch_tile", 128), hit.get("split")
+
+
+def _trunc_kernels_fit(B: int, M: int, d_raw: int, depth: int,
+                       batch_tile: int, split: int | None, precision: str,
+                       kspec=None) -> bool:
+    """Whether both truncated kernels take the signature of (B, M, d_raw)
+    increments, ``kspec`` fused: the cone forward's split (``sig_trunc``'s
+    own choice when ``split`` is None) within the VMEM limit, and the §4.2
+    backward on its kernel rather than the XLA scan."""
+    d = transform_dim(kspec, d_raw) if kspec else d_raw
+    tile, B_pad = lane_tile(B, batch_tile)
+    itemsize = 2 if precision == "bf16_fp32" else 4
+    T, n_t = time_chunk(M, 2 * block_bytes((1, d_raw, tile), itemsize))
+
+    def need(s):
+        steps = B_pad // tile * d ** s * n_t
+        return vmem_footprint(T, d_raw, d, depth, s, tile,
+                              in_itemsize=itemsize,
+                              buffers=1 if steps == 1 else 2)
+
+    s = split
+    if s is None:
+        s = choose_split(d, depth, tile)
+        while s < depth - 1 and need(s) > VMEM_LIMIT_BYTES:
+            s += 1
+    M_aug = M * kspec.sub_steps if kspec else M
+    return (0 <= s < depth and need(s) <= VMEM_LIMIT_BYTES
+            and sig_trunc_bwd.fits(B, M_aug, d, depth, batch_tile))
+
+
+def _takes_dense(wplan: WordPlan, increments: jax.Array, dense,
+                 precision: str, kspec=None) -> bool:
+    """The engine of one traced projected inverse call of the Pallas row:
+    the dense route where :func:`_dense_tiling` offered one and both
+    truncated kernels take the shape, else the word engine.  Ticks
+    ``pathsig_projected_engine_total`` by the engine picked."""
+    B, M, d_raw = increments.shape
+    ok = dense is not None and _trunc_kernels_fit(
+        B, M, d_raw, wplan.depth, *dense, precision, kspec)
+    obs.counter("pathsig_projected_engine_total",
+                "traced projected inverse calls of the Pallas row, by "
+                "engine (dense: the truncated kernels and a gather; words: "
+                "the word kernel and the projected scan)", ("engine",)).inc(
+        engine="dense" if ok else "words")
+    return ok
+
+
+@plan_cache
+def _dense_index(words: tuple, d: int) -> np.ndarray:
+    """Flat indices of a word set's coordinates in its truncated signature."""
+    return np.asarray([flat_index(w, d) for w in words], np.int32)
+
+
+def _dense_words(sig_flat: jax.Array, wplan: WordPlan) -> jax.Array:
+    """(B, D_sig) -> (B, |I|): the set's coordinates, in its order."""
+    idx = _dense_index(wplan.words, wplan.d)
+    with jax.named_scope(PROJ_INDEX_SCOPE):
+        return jnp.take(sig_flat, idx, axis=1,
+                        unique_indices=len(set(idx.tolist())) == idx.size)
+
+
+# ---------------------------------------------------------------------------
 # hybrid engine: dense W_{<=N-1} + per-word top chains (repro.core.hybrid)
 # ---------------------------------------------------------------------------
 
@@ -983,14 +1101,14 @@ def _sharded_sig(mesh, names: tuple, with_lengths: bool, depth: int,
 def _sharded_proj(mesh, names: tuple, with_lengths: bool, words: tuple,
                   d: int, engine: str, interpret: bool, backward: str,
                   batch_tile: int, max_rows: int, stream: bool,
-                  stream_stride: int, precision: str = "fp32"):
+                  stream_stride: int, precision: str = "fp32", dense=None):
     """shard_map wrapper around the projected-signature cell (incl. the
     hybrid engine).  Transforms are materialised before the mesh branch."""
     return _shard_wrap(mesh, names, with_lengths, partial(
         _projected_local, words=words, d=d, engine=engine,
         interpret=interpret, backward=backward, batch_tile=batch_tile,
         max_rows=max_rows, stream=stream, stream_stride=stream_stride,
-        precision=precision), site="sharded_proj")
+        precision=precision, dense=dense), site="sharded_proj")
 
 
 @plan_cache
@@ -1417,7 +1535,7 @@ def signature(increments: jax.Array, depth: int, *, backend: str = "auto",
         if split is None:
             split = hit.get("split")
     if obs.REGISTRY._enabled and engine == "pallas" and depth >= 1:
-        from .sig_trunc import choose_split, state_footprint
+        from .sig_trunc import state_footprint
         itemsize = 2 if precision == "bf16_fp32" else 4
         s = split if split is not None else choose_split(
             d_eff, depth, batch_tile, itemsize=itemsize)
@@ -1460,12 +1578,13 @@ def _projected_local(increments: jax.Array, lengths, *, words: tuple, d: int,
                      engine: str, interpret: bool, backward: str,
                      batch_tile: int, max_rows: int, stream: bool,
                      stream_stride: int, transform=None, x0=None,
-                     precision: str = "fp32") -> jax.Array:
+                     precision: str = "fp32", dense=None) -> jax.Array:
     """Single-device projected-signature dispatch — the body of
     :func:`projected` after validation and mesh routing (``max_rows`` is
-    already resolved from any caller-supplied TiledPlan).  Same precision
-    discipline as :func:`_signature_local`: quantise once at dispatch, every
-    engine sees the rounded values."""
+    already resolved from any caller-supplied TiledPlan; ``dense`` is
+    :func:`_dense_tiling`'s).  Same precision discipline as
+    :func:`_signature_local`: quantise once at dispatch, every engine sees
+    the rounded values."""
     wplan = _plan_for_words(words, d)
     spec = transform
     if spec is not None:
@@ -1487,7 +1606,7 @@ def _projected_local(increments: jax.Array, lengths, *, words: tuple, d: int,
         kw = dict(words=words, d=d, engine=engine, interpret=interpret,
                   backward=backward, batch_tile=batch_tile, max_rows=max_rows,
                   stream=stream, stream_stride=stream_stride,
-                  precision=precision)
+                  precision=precision, dense=dense)
         if not kspec:  # basepoint-only: one prepended increment
             return _projected_local(increments, lengths, **kw)
         B, M_bp, _ = increments.shape
@@ -1504,6 +1623,10 @@ def _projected_local(increments: jax.Array, lengths, *, words: tuple, d: int,
                 interpret, kspec, precision)(increments, taux)
             out = quantise_increments(out, precision)
             return _mask_stream_out(out, M_aug, stream_stride, aug_lengths)
+        if _takes_dense(wplan, increments, dense, precision, kspec):
+            return _dense_words(_pallas_sig_fused_inverse(
+                wplan.depth, *dense, interpret, kspec,
+                precision)(increments, taux), wplan)
         return _pallas_proj_fused_inverse(
             wplan.words, wplan.d, batch_tile, max_rows, interpret, kspec,
             precision)(increments, taux)
@@ -1536,6 +1659,9 @@ def _projected_local(increments: jax.Array, lengths, *, words: tuple, d: int,
         # cannot emit; autodiff needs scan residuals — both run on jax.
         return projected_signature_from_increments(
             increments, wplan, backward=backward, backend="jax")
+    if _takes_dense(wplan, increments, dense, precision):
+        return _dense_words(_pallas_sig_inverse(
+            wplan.depth, *dense, interpret, precision)(increments), wplan)
     return _pallas_proj_inverse(wplan.words, wplan.d, batch_tile, max_rows,
                                 interpret, precision)(increments)
 
@@ -1561,7 +1687,9 @@ def projected(increments: jax.Array, plan, *, backend: str = "auto",
     channels, ``wplan.d == transform_dim(transform, d_raw)``), the pallas
     inverse/stream cells fuse the transform into the kernel time loop, and
     every other cell materialises augmented increments once then sweeps
-    (support matrix).  ``batch_tile=None`` consults the autotuner.
+    (support matrix).  ``batch_tile=None`` consults the autotuner, under
+    ``sig_trunc`` for the dense route and ``sig_words`` for the word
+    engine.
     """
     engine, interpret = resolve_backend(backend)
     _check_backward(backward)
@@ -1587,17 +1715,22 @@ def projected(increments: jax.Array, plan, *, backend: str = "auto",
             raise unsupported_stream_backward(backward)
     if tplan is not None:  # keep the caller's tile granularity
         max_rows = max(p.closure_size for p in tplan.tiles)
+    M_eff = transform_steps(spec, increments.shape[1]) if spec \
+        else increments.shape[1]
+    dense = _dense_tiling(wplan, engine=engine, interpret=interpret,
+                          backward=backward, stream=stream,
+                          batch_tile=batch_tile, B=increments.shape[0],
+                          M=M_eff, precision=precision)
     if batch_tile is None:
         hit = autotune.lookup(
             "sig_words", engine=_tuned(engine, interpret), d=wplan.d,
-            depth=wplan.depth,
-            M=transform_steps(spec, increments.shape[1]) if spec
-            else increments.shape[1],
-            B=increments.shape[0], precision=precision)
+            depth=wplan.depth, M=M_eff, B=increments.shape[0],
+            precision=precision)
         batch_tile = hit.get("batch_tile", 128)
     kw = dict(words=wplan.words, d=wplan.d, engine=engine,
               interpret=interpret, backward=backward, batch_tile=batch_tile,
-              max_rows=max_rows, stream=stream, stream_stride=stream_stride)
+              max_rows=max_rows, stream=stream, stream_stride=stream_stride,
+              dense=dense)
     mb = _mesh_batch()
     if mb is None:
         return _projected_local(increments, lengths, **kw, transform=spec,
@@ -1614,7 +1747,7 @@ def projected(increments: jax.Array, plan, *, backend: str = "auto",
             increments = augment_increments(increments, spec, x0=x0)
     fn = _sharded_proj(mesh, names, lengths is not None, wplan.words,
                        wplan.d, engine, interpret, backward, batch_tile,
-                       max_rows, stream, stream_stride, precision)
+                       max_rows, stream, stream_stride, precision, dense)
     out = _apply_sharded(fn, size, increments, lengths)
     if stream:
         return shard_constraint(out, "batch", "path_time", "sig_words")

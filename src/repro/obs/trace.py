@@ -62,8 +62,8 @@ __all__ = [
 
 # The ``jax.named_scope`` names the program sets on its device work (the
 # op_name prefixes in compiled HLO): the custom VJPs' forward and backward,
-# the two halves of the §4.2 reverse scans' body, and the closure-table
-# gathers of the projected step.
+# the two halves of the §4.2 reverse scans' body, and the projected gathers
+# (the closure tables of the projected step, the dense route's words).
 DEVICE_SCOPES = ("pathsig.fwd", "pathsig.bwd", "pathsig.bwd.reconstruct",
                  "pathsig.bwd.cotangent", "pathsig.proj.index")
 

@@ -26,6 +26,8 @@ except ImportError:
     sys.modules["hypothesis"] = _hypothesis_fallback
     sys.modules["hypothesis.strategies"] = _hypothesis_fallback.strategies
 
+import contextlib  # noqa: E402
+
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
@@ -69,3 +71,16 @@ def rng():
 def make_path(rng, B, M, d, scale=0.3):
     return np.cumsum(rng.normal(size=(B, M + 1, d)) * scale, axis=1).astype(
         np.float32)
+
+
+@contextlib.contextmanager
+def projected_engine(engine: str):
+    """Pin the engine of ``ops.projected``'s Pallas inverse row: "words"
+    allows the dense route no truncated rows per closure row, so every word
+    set runs the word kernel and the projected §4.2 scan; "rule" leaves the
+    dispatch its own choice."""
+    from repro.kernels import ops
+    with pytest.MonkeyPatch.context() as mp:
+        if engine == "words":
+            mp.setattr(ops, "DENSE_ROWS_PER_CLOSURE_ROW", 0)
+        yield

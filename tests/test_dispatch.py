@@ -7,9 +7,15 @@ import numpy as np
 import pytest
 
 import repro.core as C
+from repro import obs
 from repro.core import tensor_ops as tops
+from repro.core.signature import mask_increments
+from repro.core.transforms import (as_transform, augment_increments,
+                                   transform_dim)
 from repro.core.words import make_plan
 from repro.kernels import ops
+from repro.kernels.sig_trunc import level_offsets
+from tests.conftest import projected_engine
 
 BACKENDS = ["jax", "pallas", "pallas_interpret", "auto"]
 WORDS = [(0,), (2, 1), (1, 1, 0), (0, 0, 1)]
@@ -52,11 +58,15 @@ def test_grad_through_signature_every_backend(backend):
     assert float(jnp.max(jnp.abs(g))) > 0
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
-def test_grad_through_projected_every_backend(backend):
+@pytest.mark.parametrize("backend,engine", [
+    *[pytest.param(b, "rule", id=b) for b in BACKENDS],
+    pytest.param("pallas_interpret", "words", id="pallas_interpret-words"),
+])
+def test_grad_through_projected_every_backend(backend, engine):
     x = _incs(1, 2, 7, 3)
-    g = _grad(backend, lambda z: ops.projected(z, _plan(), backend=backend,
-                                               batch_tile=8).sum(), x)
+    with projected_engine(engine):
+        g = _grad(backend, lambda z: ops.projected(
+            z, _plan(), backend=backend, batch_tile=8).sum(), x)
     if g is None:
         return
     assert g.shape == x.shape
@@ -123,12 +133,16 @@ def test_plan_caches_are_content_keyed():
     words = [(0,), (1, 2)]
     p1, p2 = make_plan(words, 3), make_plan(words, 3)
     assert p1 is not p2
-    ops.projected(x, p1, backend="pallas_interpret", batch_tile=8)
-    before = ops._pallas_proj_inverse.cache_info()
-    ops.projected(x, p2, backend="pallas_interpret", batch_tile=8)
-    after = ops._pallas_proj_inverse.cache_info()
-    assert after.currsize == before.currsize
-    assert after.hits == before.hits + 1
+    # the dense route's word indices, the word engine's closure VJP
+    for engine, cache in (("rule", ops._dense_index),
+                          ("words", ops._pallas_proj_inverse)):
+        with projected_engine(engine):
+            ops.projected(x, p1, backend="pallas_interpret", batch_tile=8)
+            before = cache.cache_info()
+            ops.projected(x, p2, backend="pallas_interpret", batch_tile=8)
+            after = cache.cache_info()
+        assert after.currsize == before.currsize
+        assert after.hits == before.hits + 1
     # the interned WordPlan is shared, so downstream jit caches are too
     assert ops._plan_for_words(tuple(words), 3) is \
         ops._plan_for_words(tuple(words), 3)
@@ -165,8 +179,13 @@ def test_truncated_cross_engine_gradients(B, M):
     np.testing.assert_allclose(g_cp, g_jax, rtol=1e-4, atol=1e-6)
 
 
-@pytest.mark.parametrize("B,M", [(2, 11), (1, 1)])
-def test_projected_cross_engine_values_and_gradients(B, M):
+@pytest.mark.parametrize("B,M,engine", [
+    pytest.param(2, 11, "rule", id="2-11"),
+    pytest.param(1, 1, "rule", id="1-1"),
+    pytest.param(2, 11, "words", id="2-11-words"),
+    pytest.param(1, 1, "words", id="1-1-words"),
+])
+def test_projected_cross_engine_values_and_gradients(B, M, engine):
     d = 3
     x = _incs(20 + B * M, B, M, d)
     plan = _plan()
@@ -174,15 +193,16 @@ def test_projected_cross_engine_values_and_gradients(B, M):
     dense = tops.signature_exp_chen(x, 3)
     idx = [C.flat_index(w, d) for w in WORDS]
     a = ops.projected(x, plan, backend="jax")
-    b = ops.projected(x, plan, backend="pallas_interpret", batch_tile=8)
+    with projected_engine(engine):
+        b = ops.projected(x, plan, backend="pallas_interpret", batch_tile=8)
+        g_pal = jax.grad(lambda z: jnp.sum(jnp.sin(
+            ops.projected(z, plan, backend="pallas_interpret",
+                          batch_tile=8))))(x)
     np.testing.assert_allclose(a, dense[:, idx], rtol=1e-4, atol=1e-6)
     np.testing.assert_allclose(b, dense[:, idx], rtol=1e-4, atol=1e-6)
     # gradients agree across engines
     g_jax = jax.grad(lambda z: jnp.sum(jnp.sin(
         ops.projected(z, plan, backend="jax"))))(x)
-    g_pal = jax.grad(lambda z: jnp.sum(jnp.sin(
-        ops.projected(z, plan, backend="pallas_interpret",
-                      batch_tile=8))))(x)
     np.testing.assert_allclose(g_pal, g_jax, rtol=1e-4, atol=1e-6)
 
 
@@ -259,9 +279,11 @@ def test_float64_dtype_preserved_across_engines():
         assert a.dtype == jnp.float64
         assert b.dtype == jnp.float64  # kernel computes f32, restores dtype
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
-        p = ops.projected(x, _plan(), backend="pallas_interpret",
-                          batch_tile=8)
-        assert p.dtype == jnp.float64
+        for engine in ("rule", "words"):
+            with projected_engine(engine):
+                p = ops.projected(x, _plan(), backend="pallas_interpret",
+                                  batch_tile=8)
+            assert p.dtype == jnp.float64
         g = jax.grad(lambda z: ops.signature(
             z, 3, backend="pallas_interpret", batch_tile=8).sum())(x)
         assert g.dtype == jnp.float64
@@ -359,3 +381,134 @@ def test_hybrid_backend_forward_only():
     a = np.asarray(ops.projected_forward_only(x, plan, backend="hybrid"))
     b = np.asarray(ops.projected(x, plan, backend="jax"))
     np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the projected Pallas inverse row's two engines: the dense route (the
+# truncated kernels to the set's depth, then a gather) and the word engine
+# (the closure kernel and the projected §4.2 scan)
+# ---------------------------------------------------------------------------
+
+# d = 3, depth 6: 1,128 truncated rows against a closure of 9
+SPARSE_WORDS = ((0, 1, 2, 0, 1, 2), (1,), (2, 1))
+
+
+def _projected_engine_ticks():
+    c = obs.counter("pathsig_projected_engine_total", labelnames=("engine",))
+    return {e: c.value(engine=e) for e in ("dense", "words")}
+
+
+def _oracle_words(incs, words, spec, x0, lens):
+    """The exp/Chen signature of the materialised (transformed, masked)
+    increments, read at the words."""
+    if spec is None:
+        e = incs if lens is None else mask_increments(incs, lens)
+    elif lens is None:
+        e = augment_increments(incs, spec, x0=x0)
+    else:
+        e, al = augment_increments(incs, spec, x0=x0, lengths=lens)
+        e = mask_increments(e, al)
+    depth = max(len(w) for w in words)
+    dense = tops.signature_exp_chen(e, depth)
+    return dense[:, [C.flat_index(w, e.shape[-1]) for w in words]]
+
+
+@pytest.mark.parametrize("tname,ragged", [
+    pytest.param(None, False, id="plain"),
+    pytest.param(None, True, id="plain-ragged"),
+    pytest.param("lead_lag", False, id="lead_lag"),
+    pytest.param("lead_lag", True, id="lead_lag-ragged"),
+    pytest.param("time_augment", False, id="time_augment"),
+    pytest.param("basepoint", True, id="basepoint-ragged"),
+    pytest.param("basepoint+lead_lag", False, id="basepoint-lead_lag"),
+])
+def test_dense_route_matches_oracle_and_word_engine(tname, ragged):
+    B, M, d_raw = 3, 6, 2
+    spec = as_transform(tname)
+    d = transform_dim(spec, d_raw) if spec else d_raw
+    words = tuple(C.all_words(d, 3))[1::4]
+    x = _incs(70 + d, B, M, d_raw)
+    x0 = _incs(71, B, 1, d_raw)[:, 0]
+    lens = jnp.asarray([6, 3, 0]) if ragged else None
+
+    def proj(z):
+        return ops.projected(z, words, backend="pallas_interpret",
+                             batch_tile=8, transform=tname, x0=x0,
+                             lengths=lens)
+
+    def grad(z):
+        return jax.grad(lambda z: jnp.sum(jnp.sin(proj(z))))(z)
+
+    with obs.enabled_scope():
+        before = _projected_engine_ticks()
+        val, g = proj(x), grad(x)
+        after = _projected_engine_ticks()
+    assert after == {**before, "dense": before["dense"] + 2}
+    np.testing.assert_allclose(
+        val, _oracle_words(x, words, spec, x0, lens), rtol=1e-4, atol=1e-6)
+    g_jax = jax.grad(lambda z: jnp.sum(jnp.sin(ops.projected(
+        z, words, backend="jax", transform=tname, x0=x0,
+        lengths=lens))))(x)
+    np.testing.assert_allclose(g, g_jax, rtol=1e-4, atol=1e-6)
+    with projected_engine("words"), obs.enabled_scope():
+        before = _projected_engine_ticks()
+        val_w, g_w = proj(x), grad(x)
+        after = _projected_engine_ticks()
+    assert after == {**before, "words": before["words"] + 2}
+    np.testing.assert_allclose(val, val_w, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(g, g_w, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("tname", [None, "lead_lag"])
+def test_sparse_set_stays_on_word_engine(tname):
+    """The rule's other side: a set whose truncated tensor has more than
+    DENSE_ROWS_PER_CLOSURE_ROW rows per closure row runs the word kernel
+    and the projected scan."""
+    spec = as_transform(tname)
+    d_raw = 3 if spec is None else 2
+    words = SPARSE_WORDS if spec is None else ((0, 1, 2, 3, 0, 1), (3, 2))
+    d = transform_dim(spec, d_raw) if spec else d_raw
+    plan = make_plan(words, d)
+    assert int(level_offsets(d, plan.depth)[-1]) > \
+        ops.DENSE_ROWS_PER_CLOSURE_ROW * plan.closure_size
+    x = _incs(80, 2, 5, d_raw)
+
+    def proj(z, backend="pallas_interpret"):
+        return ops.projected(z, plan, backend=backend, batch_tile=8,
+                             transform=tname)
+
+    with obs.enabled_scope():
+        before = _projected_engine_ticks()
+        val = proj(x)
+        g = jax.grad(lambda z: jnp.sum(proj(z) ** 2))(x)
+        after = _projected_engine_ticks()
+    assert after == {**before, "words": before["words"] + 2}
+    np.testing.assert_allclose(val, _oracle_words(x, words, spec, None, None),
+                               rtol=1e-4, atol=1e-7)
+    g_jax = jax.grad(lambda z: jnp.sum(proj(z, "jax") ** 2))(x)
+    np.testing.assert_allclose(g, g_jax, rtol=1e-4, atol=1e-7)
+
+
+@pytest.mark.parametrize("B,M,d_raw,depth,tname,fits", [
+    (1024, 250, 5, 3, "lead_lag", True),     # the §8 set's shape
+    (1024, 500, 4, 5, None, True),           # paper Table 1 widths
+    (1, 2, 8, 6, None, False),               # S and G overflow VMEM
+    (2, 0, 3, 3, None, False),               # no time axis to sweep
+])
+def test_dense_rule_needs_both_truncated_kernels(B, M, d_raw, depth, tname,
+                                                 fits):
+    assert ops._trunc_kernels_fit(B, M, d_raw, depth, 128, None, "fp32",
+                                  as_transform(tname)) is fits
+
+
+def test_projected_engine_counter_ticks_once_per_trace():
+    x = _incs(90, 2, 5, 3)
+    f = jax.jit(lambda z: ops.projected(z, _plan(),
+                                        backend="pallas_interpret",
+                                        batch_tile=8))
+    with obs.enabled_scope():
+        before = _projected_engine_ticks()
+        f(x)
+        f(x + 1.0)
+        after = _projected_engine_ticks()
+    assert after == {**before, "dense": before["dense"] + 1}

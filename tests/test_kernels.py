@@ -11,6 +11,7 @@ from repro.core.words import (all_words, anisotropic_words, lyndon_words,
 from repro.kernels import ops, ref, sig_trunc_bwd
 from repro.kernels.sig_trunc import (choose_split, cone_rows, from_rows,
                                      level_offsets, reverse_words, sig_trunc)
+from tests.conftest import projected_engine
 
 
 def _incs(seed, B, M, d, dtype=np.float32):
@@ -141,12 +142,20 @@ def test_sig_trunc_property(d, N, M, B):
 # sig_words: arbitrary word sets × tilings
 # ---------------------------------------------------------------------------
 
+def _word_kernel(x, plan):
+    """``ops.projected`` on the word kernel, whatever the dense route's
+    rule would pick for the set."""
+    with projected_engine("words"):
+        return ops.projected(x, plan, backend="pallas_interpret",
+                             batch_tile=8)
+
+
 @pytest.mark.parametrize("max_rows", [8, 32, 512])
 def test_sig_words_full_truncation(max_rows):
     d, N = 3, 4
     x = _incs(7, 3, 9, d)
     tp = make_tiled_plan(all_words(d, N), d, max_rows=max_rows)
-    got = ops.projected(x, tp, backend="pallas_interpret", batch_tile=8)
+    got = _word_kernel(x, tp)
     np.testing.assert_allclose(got, ref.sig_trunc_ref(x, N),
                                rtol=2e-4, atol=2e-5)
 
@@ -155,7 +164,7 @@ def test_sig_words_sparse_set():
     d = 4
     wordset = [(0,), (3, 2), (1, 1, 1, 1), (2, 0, 3), (3, 3)]
     x = _incs(8, 2, 14, d)
-    got = ops.projected(x, wordset, backend="pallas_interpret", batch_tile=8)
+    got = _word_kernel(x, wordset)
     want = ref.sig_words_ref(x, wordset, d)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     # cross-check each coefficient against the dense oracle
@@ -171,7 +180,7 @@ def test_sig_words_anisotropic():
     ws = anisotropic_words(gamma, r)
     x = _incs(9, 2, 8, 3)
     tp = make_tiled_plan(ws, 3, max_rows=16)
-    got = ops.projected(x, tp, backend="pallas_interpret", batch_tile=8)
+    got = _word_kernel(x, tp)
     np.testing.assert_allclose(got, ref.sig_words_ref(x, ws, 3),
                                rtol=2e-4, atol=2e-5)
 
@@ -182,7 +191,7 @@ def test_sig_words_lyndon_projection():
     ws = all_words(d, N - 1) + [w for w in lyndon_words(d, N) if len(w) == N]
     x = _incs(10, 2, 9, d)
     tp = make_tiled_plan(ws, d, max_rows=24)
-    got = ops.projected(x, tp, backend="pallas_interpret", batch_tile=8)
+    got = _word_kernel(x, tp)
     np.testing.assert_allclose(got, ref.sig_words_ref(x, ws, d),
                                rtol=2e-4, atol=2e-5)
 
@@ -198,7 +207,7 @@ def test_sig_words_property(d, data):
     x = _incs(data.draw(st.integers(0, 10**6)), 2, M, d)
     max_rows = data.draw(st.sampled_from([8, 16, 128]))
     tp = make_tiled_plan(wordset, d, max_rows=max_rows)
-    got = ops.projected(x, tp, backend="pallas_interpret", batch_tile=8)
+    got = _word_kernel(x, tp)
     want = ref.sig_words_ref(x, wordset, d)
     np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-5)
 

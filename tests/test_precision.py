@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.kernels import ops
 from repro.kernels.sig_trunc import choose_split, state_footprint
 from repro.kernels.sig_words import tile_footprint
+from tests.conftest import projected_engine
 
 DEPTH = 6
 B, M, d = 4, 40, 3
@@ -84,11 +85,14 @@ def test_bf16_projected_and_gram(incs):
     from repro.core.words import all_words
     words = tuple(all_words(d, 3))
     ref = ops.projected(incs, words, backend="jax")
-    got = ops.projected(incs, words, backend="pallas_interpret",
-                        precision="bf16_fp32", batch_tile=8)
-    # projections are signature coordinates: same per-level (relative) bound
-    rel = float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
-    assert rel < 3 * 2.0 ** -8
+    for engine in ("rule", "words"):
+        with projected_engine(engine):
+            got = ops.projected(incs, words, backend="pallas_interpret",
+                                precision="bf16_fp32", batch_tile=8)
+        # projections are signature coordinates: same per-level (relative)
+        # bound
+        rel = float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
+        assert rel < 3 * 2.0 ** -8, engine
     S = ops.signature(incs, 3, backend="jax")
     w = jnp.ones(S.shape[1], jnp.float32)
     g32 = ops.gram(S, S, w, backend="pallas_interpret")

@@ -28,7 +28,7 @@ from repro.core.words import make_plan
 from repro.ragged import (RaggedPaths, assign_buckets, bucket_ladder,
                           bucket_paths, pad_batch)
 
-from tests.conftest import make_path
+from tests.conftest import make_path, projected_engine
 
 BACKENDS = ("jax", "pallas_interpret")
 WORDS = ((0,), (1,), (0, 1), (1, 0, 1))
@@ -88,20 +88,26 @@ def test_ragged_terminal_matches_unpadded(rng, backend, backward):
                                    atol=1e-6)
 
 
-@pytest.mark.parametrize("backend", BACKENDS + ("hybrid",))
-@pytest.mark.parametrize("backward", ["inverse", "checkpoint", "autodiff"])
-def test_ragged_projected_matches_unpadded(rng, backend, backward):
+@pytest.mark.parametrize("backward,backend,engine", [
+    *[pytest.param(bw, b, "rule", id=f"{bw}-{b}")
+      for bw in ("inverse", "checkpoint", "autodiff")
+      for b in BACKENDS + ("hybrid",)],
+    pytest.param("inverse", "pallas_interpret", "words",
+                 id="inverse-pallas_interpret-words"),
+])
+def test_ragged_projected_matches_unpadded(rng, backend, backward, engine):
     path, lengths = ragged_batch(rng)
     plan = make_plan(WORDS, 2)
-    out = projected_signature(path, plan.words, 2, plan=plan,
-                              backend=backend, backward=backward,
-                              lengths=lengths)
-    for b, L in enumerate(lengths):
-        ref = projected_signature(path[b:b + 1, :L + 1], plan.words, 2,
-                                  plan=plan, backend=backend,
-                                  backward=backward)[0]
-        np.testing.assert_allclose(np.asarray(out[b]), np.asarray(ref),
-                                   atol=1e-6)
+    with projected_engine(engine):
+        out = projected_signature(path, plan.words, 2, plan=plan,
+                                  backend=backend, backward=backward,
+                                  lengths=lengths)
+        for b, L in enumerate(lengths):
+            ref = projected_signature(path[b:b + 1, :L + 1], plan.words, 2,
+                                      plan=plan, backend=backend,
+                                      backward=backward)[0]
+            np.testing.assert_allclose(np.asarray(out[b]), np.asarray(ref),
+                                       atol=1e-6)
 
 
 @settings(max_examples=8, deadline=None)
@@ -206,8 +212,11 @@ def test_ragged_grads_zero_past_end(rng, backend, backward, stream):
         assert np.any(g[b, :L + 1] != 0.0)
 
 
-@pytest.mark.parametrize("backend", BACKENDS + ("hybrid",))
-def test_ragged_projected_grads_zero_past_end(rng, backend):
+@pytest.mark.parametrize("backend,engine", [
+    *[pytest.param(b, "rule", id=b) for b in BACKENDS + ("hybrid",)],
+    pytest.param("pallas_interpret", "words", id="pallas_interpret-words"),
+])
+def test_ragged_projected_grads_zero_past_end(rng, backend, engine):
     path, lengths = ragged_batch(rng)
     plan = make_plan(WORDS, 2)
 
@@ -216,7 +225,8 @@ def test_ragged_projected_grads_zero_past_end(rng, backend):
                                   backend=backend, lengths=lengths)
         return jnp.sum(out ** 2)
 
-    g = np.asarray(jax.grad(loss)(path))
+    with projected_engine(engine):
+        g = np.asarray(jax.grad(loss)(path))
     for b, L in enumerate(lengths):
         assert np.all(g[b, L + 1:] == 0.0)
 

@@ -8,14 +8,16 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import projected_signature
+from repro.core import all_words, projected_signature
 from repro.kernels import ops
 from repro.obs.trace import DEVICE_SCOPES
+from tests.conftest import projected_engine
 
 FWD, BWD, RECONSTRUCT, COTANGENT, PROJ_INDEX = DEVICE_SCOPES
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 WORDS = ((0,), (1,), (0, 1), (1, 0), (0, 1, 2), (2, 2, 1))
 LEADLAG_WORDS = ((0,), (3,), (0, 3), (3, 0), (1, 4, 2))
+DENSE_LEADLAG_WORDS = tuple(all_words(6, 2)) + ((1, 4, 2),)
 
 
 def _trunc(backward, stream=False, backend="jax"):
@@ -26,11 +28,13 @@ def _trunc(backward, stream=False, backend="jax"):
     return sig
 
 
-def _proj(backward, backend="jax", transform=None, words=WORDS):
+def _proj(backward, backend="jax", transform=None, words=WORDS,
+          engine="rule"):
     def sig(x):
-        return projected_signature(jnp.cumsum(x, axis=1), words,
-                                   backward=backward, backend=backend,
-                                   transform=transform)
+        with projected_engine(engine):
+            return projected_signature(jnp.cumsum(x, axis=1), words,
+                                       backward=backward, backend=backend,
+                                       transform=transform)
     return sig
 
 
@@ -46,8 +50,13 @@ CASES = {
                        False),
     "hybrid_inverse": (_proj("inverse", backend="hybrid"), True, False),
     "fused_leadlag": (_proj("inverse", backend="pallas_interpret",
-                            transform="lead_lag", words=LEADLAG_WORDS),
+                            transform="lead_lag", words=LEADLAG_WORDS,
+                            engine="words"),
                       True, True),
+    # the dense route: both truncated kernels, then a gather of the words
+    "dense_leadlag": (_proj("inverse", backend="pallas_interpret",
+                            transform="lead_lag", words=DENSE_LEADLAG_WORDS),
+                      False, True),
 }
 
 

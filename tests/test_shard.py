@@ -407,39 +407,42 @@ def test_make_dev_mesh_validates_device_count():
 def test_plan_caches_bounded_eviction_and_clear():
     """A maxsize-1 plan-cache policy forces eviction on every alternation of
     word sets — results must be identical to the unbounded policy, and
-    clear_plan_caches() must be a pure perf event."""
+    clear_plan_caches() must be a pure perf event.  The word engine's closure
+    VJP is keyed by the word set, so it runs here."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops
+    from tests.conftest import projected_engine
 
-    x = jax.random.normal(jax.random.PRNGKey(0), (3, 8, 2)) * 0.3
-    sets = [((0,), (1, 0)), ((1,), (0, 1), (1, 1, 0)), ((0, 0), (1, 0, 1))]
-    ref = [np.asarray(ops.projected(x, ws, backend="pallas_interpret"))
-           for ws in sets]
-    gref = [np.asarray(jax.grad(lambda a, ws=ws: ops.projected(
-        a, ws, backend="pallas_interpret").sum())(x)) for ws in sets]
+    with projected_engine("words"):
+        x = jax.random.normal(jax.random.PRNGKey(0), (3, 8, 2)) * 0.3
+        sets = [((0,), (1, 0)), ((1,), (0, 1), (1, 1, 0)), ((0, 0), (1, 0, 1))]
+        ref = [np.asarray(ops.projected(x, ws, backend="pallas_interpret"))
+               for ws in sets]
+        gref = [np.asarray(jax.grad(lambda a, ws=ws: ops.projected(
+            a, ws, backend="pallas_interpret").sum())(x)) for ws in sets]
 
-    old = ops.PLAN_CACHE_MAXSIZE
-    try:
-        ops.set_plan_cache_maxsize(1)
-        for _ in range(2):              # alternate -> evict every call
-            for i, ws in enumerate(sets):
-                got = np.asarray(ops.projected(x, ws,
-                                               backend="pallas_interpret"))
-                np.testing.assert_array_equal(got, ref[i])
-                ggot = np.asarray(jax.grad(lambda a, ws=ws: ops.projected(
-                    a, ws, backend="pallas_interpret").sum())(x))
-                np.testing.assert_array_equal(ggot, gref[i])
-        info = ops.plan_cache_info()
-        assert info["_pallas_proj_inverse"].maxsize == 1
-        assert info["_pallas_proj_inverse"].misses > len(sets)  # evictions
-        ops.clear_plan_caches()
-        assert ops.plan_cache_info()["_plan_for_words"].currsize == 0
-        got = np.asarray(ops.projected(x, sets[0],
-                                       backend="pallas_interpret"))
-        np.testing.assert_array_equal(got, ref[0])
-    finally:
-        ops.set_plan_cache_maxsize(old)
+        old = ops.PLAN_CACHE_MAXSIZE
+        try:
+            ops.set_plan_cache_maxsize(1)
+            for _ in range(2):              # alternate -> evict every call
+                for i, ws in enumerate(sets):
+                    got = np.asarray(ops.projected(x, ws,
+                                                   backend="pallas_interpret"))
+                    np.testing.assert_array_equal(got, ref[i])
+                    ggot = np.asarray(jax.grad(lambda a, ws=ws: ops.projected(
+                        a, ws, backend="pallas_interpret").sum())(x))
+                    np.testing.assert_array_equal(ggot, gref[i])
+            info = ops.plan_cache_info()
+            assert info["_pallas_proj_inverse"].maxsize == 1
+            assert info["_pallas_proj_inverse"].misses > len(sets)  # evictions
+            ops.clear_plan_caches()
+            assert ops.plan_cache_info()["_plan_for_words"].currsize == 0
+            got = np.asarray(ops.projected(x, sets[0],
+                                           backend="pallas_interpret"))
+            np.testing.assert_array_equal(got, ref[0])
+        finally:
+            ops.set_plan_cache_maxsize(old)
 
 
 def test_plan_cache_policy_is_shared():

@@ -9,6 +9,7 @@ import repro.sigkernel as SK
 from repro.core import anisotropic_words, sig_dim
 from repro.core import tensor_ops as tops
 from repro.kernels import ops
+from tests.conftest import projected_engine
 
 
 def make_path(rng, B, M, d, scale=0.3):
@@ -33,15 +34,19 @@ def test_gram_truncated_matches_oracle(rng, backend):
     np.testing.assert_allclose(got, ref, atol=1e-5 * np.abs(ref).max())
 
 
-@pytest.mark.parametrize("backend", GRAM_BACKENDS)
-def test_gram_projected_words_matches_oracle(rng, backend):
+@pytest.mark.parametrize("backend,engine", [
+    *[pytest.param(b, "rule", id=b) for b in GRAM_BACKENDS],
+    pytest.param("pallas_interpret", "words", id="pallas_interpret-words"),
+])
+def test_gram_projected_words_matches_oracle(rng, backend, engine):
     x = make_path(rng, 6, 25, 3)
     y = make_path(rng, 4, 25, 3)
     words = anisotropic_words((1.0, 1.0, 2.0), 4.0)
     ref = np.asarray(SK.sig_gram(x, y, words=words, route="oracle",
                                  backend="jax"))
-    got = np.asarray(SK.sig_gram(x, y, words=words, route="tiled",
-                                 backend=backend, block_words=16))
+    with projected_engine(engine):
+        got = np.asarray(SK.sig_gram(x, y, words=words, route="tiled",
+                                     backend=backend, block_words=16))
     assert ref.shape == (6, 4)
     np.testing.assert_allclose(got, ref, atol=1e-5 * np.abs(ref).max())
 

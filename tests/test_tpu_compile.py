@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.transforms import as_transform
-from repro.core.words import all_words, anisotropic_words
+from repro.core.words import all_words, anisotropic_words, generated_words
 from repro.kernels import ops, sig_trunc_bwd
 from repro.kernels.sig_gram import sig_gram_tiles
 from repro.kernels.sig_trunc import sig_trunc
@@ -93,6 +93,25 @@ def test_long_fused_training_step_compiles(one_chip, B):
     step = jax.value_and_grad(lambda x, t: jnp.sum(sig(x, t) ** 2))
     txt = _compiled_text(step, _f32(one_chip, B, 17_983, 6),
                          _f32(one_chip, B, 2))
+    assert txt.count("tpu_custom_call") == 2
+
+
+def test_dense_projected_training_step_compiles(one_chip):
+    """Paper §8's sparse lead-lag words over 5 channels at depth 3, 1,024
+    paths of 250 steps: the dense route's cone forward with the lead-lag
+    fused, its §4.2 kernel backward and the words' gather."""
+    d = 5
+    gens = [(d + i,) for i in range(d)] + [
+        w for i in range(d) for w in ((i, d + i), (d + i, i))]
+    wplan = ops._plan_for_words(tuple(generated_words(gens, 3)), 2 * d)
+    assert (len(wplan.words), wplan.closure_size) == (260, 285)
+    kspec = as_transform("lead_lag")
+    assert ops._trunc_kernels_fit(1024, 250, d, 3, 128, None, "fp32", kspec)
+    sig = ops._pallas_sig_fused_inverse(3, 128, None, False, kspec, "fp32")
+    step = jax.value_and_grad(lambda x, t: jnp.sum(jnp.sin(
+        ops._dense_words(sig(x, t), wplan))))
+    txt = _compiled_text(step, _f32(one_chip, 1024, 250, d),
+                         _f32(one_chip, 1024, 2))
     assert txt.count("tpu_custom_call") == 2
 
 

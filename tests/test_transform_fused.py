@@ -17,6 +17,7 @@ from repro.core.transforms import (apply_transform, as_transform,
                                    transform_dim, transform_lengths)
 from repro.core.words import all_words
 from repro.kernels import ops
+from tests.conftest import projected_engine
 
 B, M, d, DEPTH = 5, 11, 2, 3
 LENGTHS = np.asarray([11, 7, 1, 0, 5])
@@ -119,9 +120,14 @@ def test_fused_stream_matches_materialised(data, backend, stride, ragged):
     np.testing.assert_allclose(np.asarray(gi), np.asarray(ri), atol=3e-5)
 
 
-@pytest.mark.parametrize("ragged", [False, True], ids=["full", "ragged"])
-@pytest.mark.parametrize("backend", ["pallas_interpret", "jax", "hybrid"])
-def test_fused_projected_matches_materialised(data, backend, ragged):
+@pytest.mark.parametrize("backend,ragged,engine", [
+    *[pytest.param(b, r, "rule", id=f"{b}-{'ragged' if r else 'full'}")
+      for b in ("pallas_interpret", "jax", "hybrid") for r in (False, True)],
+    *[pytest.param("pallas_interpret", r, "words",
+                   id=f"pallas_interpret-{'ragged' if r else 'full'}-words")
+      for r in (False, True)],
+])
+def test_fused_projected_matches_materialised(data, backend, ragged, engine):
     path, incs, x0 = data
     spec = as_transform("time_augment+lead_lag")
     words = tuple(all_words(transform_dim(spec, d), 3))[:40]
@@ -129,14 +135,15 @@ def test_fused_projected_matches_materialised(data, backend, ragged):
     al = None if lens is None else transform_lengths(spec, lens)
     ref = ops.projected(_oracle_incs(path, spec, lens), words, backend="jax",
                         lengths=al)
-    got = ops.projected(incs, words, backend=backend, transform=spec,
-                        lengths=lens, batch_tile=8)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=3e-6)
     co = jnp.asarray(np.random.default_rng(3).standard_normal(
         ref.shape).astype(np.float32))
-    gi = jax.grad(lambda x: jnp.vdot(ops.projected(
-        x, words, backend=backend, transform=spec, lengths=lens,
-        batch_tile=8), co))(incs)
+    with projected_engine(engine):
+        got = ops.projected(incs, words, backend=backend, transform=spec,
+                            lengths=lens, batch_tile=8)
+        gi = jax.grad(lambda x: jnp.vdot(ops.projected(
+            x, words, backend=backend, transform=spec, lengths=lens,
+            batch_tile=8), co))(incs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=3e-6)
     ri = jax.grad(lambda x: jnp.vdot(ops.projected(
         _oracle_incs(jnp.concatenate(
             [path[:, :1], path[:, :1] + jnp.cumsum(x, 1)], 1), spec, lens),
